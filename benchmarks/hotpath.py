@@ -71,6 +71,16 @@ MICRO_REPS = 100               # sub-ms metrics: min over a longer window
                                # so one background burst can't poison it
 
 
+def _require_cpu_host():
+    """This is a CPU-host tool: its procs and sharded phases spawn child
+    processes, and a child cannot reach a chip this process holds."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise SystemExit(
+            f"benchmarks.hotpath measures the CPU host and needs "
+            f"JAX_PLATFORMS=cpu; found backend {backend!r}")
+
+
 def _require(ok, msg):
     """assert that survives python -O: the timed closures' work must not
     silently vanish (stripped asserts would time empty functions)."""
@@ -807,6 +817,7 @@ def run_bench(*, sharded: bool = False,
               serve: bool = False,
               transport: bool = False,
               imagine_fused: bool = False) -> dict:
+    _require_cpu_host()
     metrics = {}
     bench_worker_steps(metrics)
     bench_parameter_server(metrics)
@@ -826,7 +837,7 @@ def run_bench(*, sharded: bool = False,
         bench_sharded(metrics)
     return {
         "bench": "hotpath",
-        "backend": jax.default_backend(),
+        "backend": "cpu",
         "invariants": {
             "no_retrace_after_warmup":
                 metrics["train_epoch_compiles_after_warmup"] == 0,

@@ -1,10 +1,12 @@
 """Roofline analysis from the dry-run's compiled artifacts.
 
-Three terms per (arch x shape x mesh), in seconds per step:
+Three terms per (arch x shape x mesh), in seconds per step, against the
+per-chip peaks of the device the dry-run targets (``PEAKS``, keyed by
+jax's ``device_kind``; the production meshes are v5e pods):
 
-  t_compute    = FLOPs_per_device / 197e12          (v5e bf16 peak)
-  t_memory     = HBM_bytes_per_device / 819e9
-  t_collective = collective_bytes_per_device / 50e9 (ICI per link)
+  t_compute    = FLOPs_per_device / peak bf16 FLOP/s
+  t_memory     = HBM_bytes_per_device / peak HBM bytes/s
+  t_collective = collective_bytes_per_device / ICI bytes/s per link
 
 Collective bytes come from the compiled HLO (parsed + while-loop trip
 scaling in repro.launch.dryrun.collective_bytes) — the real artifact.
@@ -24,9 +26,24 @@ import json
 from pathlib import Path
 from typing import Dict, List
 
-PEAK_FLOPS = 197e12     # bf16 / chip
-HBM_BW = 819e9          # bytes/s
-ICI_BW = 50e9           # bytes/s/link
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819
+# GB/s HBM, 1,600 Gbit/s interconnect = 4 links of 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s_per_link": 50e9},
+}
+# the dry-run compiles for launch/mesh.py's v5e pod meshes
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; add them to PEAKS with a source")
+    return PEAKS[device_kind]
 
 F32, BF16 = 4, 2
 
@@ -166,6 +183,7 @@ def one_sentence(bottleneck, cfg, shape_kind) -> str:
 
 def roofline_table(records: List[dict]) -> List[dict]:
     from repro.models.config import INPUT_SHAPES
+    pk = peaks(DRYRUN_DEVICE_KIND)
     rows = []
     for r in records:
         if not r.get("ok"):
@@ -177,11 +195,11 @@ def roofline_table(records: List[dict]) -> List[dict]:
         B, S = shape.global_batch, shape.seq_len
         fl = flops_per_step(cfg, shape.kind, B, S, r["params"],
                             r["active_params"])
-        t_compute = fl["total"] / chips / PEAK_FLOPS
+        t_compute = fl["total"] / chips / pk["flops"]
         hbm = hbm_bytes_per_device(cfg, shape.kind, B, S, r["params"],
                                    chips, r["mesh"],
                                    r.get("num_microbatches", 1))
-        t_memory = hbm / HBM_BW
+        t_memory = hbm / pk["hbm_bytes_per_s"]
         cc = r.get("collectives", {})
         if "ici_bytes" in cc:
             coll = cc["ici_bytes"]
@@ -195,7 +213,7 @@ def roofline_table(records: List[dict]) -> List[dict]:
                     + cc.get("reduce-scatter", 0) * (g - 1)
                     + cc.get("all-to-all", 0) * (g - 1) / g
                     + cc.get("collective-permute", 0))
-        t_coll = coll / ICI_BW
+        t_coll = coll / pk["ici_bytes_per_s_per_link"]
         terms = {"compute": t_compute, "memory": t_memory,
                  "collective": t_coll}
         bottleneck = max(terms, key=terms.get)

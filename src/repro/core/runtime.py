@@ -100,12 +100,12 @@ class RunConfig:
     snapshot_every_s: float = 2.0
     ckpt_dir: Optional[str] = None
     max_restarts: int = 3
-    # procs mode: after the collector reaches total_trajs, keep the
-    # learner processes running until their servers reach these versions
+    # threads/procs modes: after the collectors reach total_trajs, keep
+    # the learners running until their servers reach these versions
     # (0 = stop immediately, the paper's pure criterion). A simulated
     # collector can outrun the learners' first XLA compile entirely; CI
-    # uses this to assert the run actually trained. The model worker
-    # only pushes after min_warmup_trajs, so never set
+    # and chip_smoke.py use this to assert the run actually trained.
+    # The model worker only pushes after min_warmup_trajs, so never set
     # min_final_model_version > 0 with total_trajs < min_warmup_trajs.
     min_final_model_version: int = 0
     min_final_policy_version: int = 0
@@ -338,6 +338,13 @@ class AsyncTrainer:
                 f"loop only (got mode={mode!r})")
         self.supervisor = supervisor
         if mode == "procs":
+            if jax.default_backend() == "tpu":
+                # the parent builds both learners here, so it holds the
+                # chip before any child starts; a child then cannot get it
+                raise ValueError(
+                    'mode="procs" cannot run on TPU: the parent process '
+                    "holds the chip and its spawned workers cannot reach "
+                    'it. Use mode="threads" (one process, one chip).')
             if algo_cfg is None or pol_cfg is None:
                 raise ValueError(
                     'mode="procs" needs algo_cfg= and pol_cfg= (children '
@@ -535,7 +542,21 @@ class AsyncTrainer:
         # never overshoot the paper's global criterion
         ds.set_target(rc.total_trajs)
 
-        collect_errors: List[tuple] = []
+        # a worker thread that raises would otherwise die with only a
+        # stderr traceback: a dead collector strands its claimed tickets,
+        # a dead learner leaves the run 'passing' at version 0. Record
+        # (role, error), stop everyone, and re-raise the FIRST one from
+        # the main thread after the joins.
+        errors: List[tuple] = []
+
+        def guarded(role, loop, *args):
+            def run():
+                try:
+                    loop(*args)
+                except Exception as e:
+                    errors.append((role, e))
+                    stop.set()
+            return threading.Thread(target=run, daemon=True, name=role)
 
         def collect_loop(w):
             while not stop.is_set():
@@ -546,16 +567,7 @@ class AsyncTrainer:
                 if not g:
                     break
                 t_step = time.monotonic()
-                try:
-                    dur = w.step(g)
-                except Exception as e:
-                    # a dead thread cannot refund its claimed tickets, so
-                    # the run would otherwise 'complete' trajectories
-                    # short with only a stderr traceback — record it and
-                    # re-raise from the MAIN thread after the joins
-                    collect_errors.append((w.collector_id, e))
-                    stop.set()
-                    return
+                dur = w.step(g)
                 if rc.pace_collection and dur is not None:
                     # emulate the robot's control frequency: a trajectory
                     # occupies `dur` seconds of real time regardless of
@@ -581,22 +593,25 @@ class AsyncTrainer:
                     time.sleep(0.002)
 
         collect_threads = [
-            threading.Thread(target=collect_loop, args=(w,), daemon=True,
-                             name=f"collect:{w.collector_id}")
+            guarded(f"collector {w.collector_id}", collect_loop, w)
             for w in self.collectors]
-        learner_threads = [threading.Thread(target=f, daemon=True)
-                           for f in (model_loop, policy_loop)]
+        learner_threads = [guarded("model learner", model_loop),
+                           guarded("policy learner", policy_loop)]
         for th in collect_threads + learner_threads:
             th.start()
         for th in collect_threads:  # every claimed slot has been pushed
             th.join()               # once the whole fleet exits
+        while not stop.is_set() and (
+                self.model_server.version < rc.min_final_model_version
+                or self.policy_server.version < rc.min_final_policy_version):
+            time.sleep(0.01)        # learners still owe their versions
         stop.set()
         for th in learner_threads:
             th.join(timeout=10)
-        if collect_errors:
-            cid, err = collect_errors[0]
+        if errors:
+            role, err = errors[0]
             raise RuntimeError(
-                f"collector {cid} failed mid-run; the fleet stopped at "
+                f"{role} failed mid-run; the fleet stopped at "
                 f"{ds.total_pushed}/{rc.total_trajs} trajectories"
             ) from err
         self._keval, k = jax.random.split(self._keval)

@@ -49,6 +49,7 @@ from repro.core.servers import DataServer, ParameterServer, ReplayBuffer
 from repro.mbrl import dynamics as DYN
 from repro.mbrl import policy as PI
 from repro.mbrl.early_stop import EMAEarlyStop
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.jit_stats import jit_cache_size
 
 
@@ -371,6 +372,10 @@ class ModelLearningWorker:
             DYN.make_ring_trainer(self.cfg, self.buffer.capacity,
                                   batch_sharding=self._batch_shard)
         self.opt_state = opt.init(self.params)
+        if self._repl is not None:
+            # the step counter is born on the default device; the first
+            # epoch returns it replicated, which would retrace the second
+            self.opt_state = jax.device_put(self.opt_state, self._repl)
 
     def compile_count(self) -> int:
         """Traces of the ring ``train_epoch`` (exact, via TraceCounted).
@@ -671,6 +676,7 @@ def proc_worker_main(role: str, spec: ProcSpec, ch: ProcChannels,
     boundary except host arrays through the IPC servers. Fleet
     collectors are addressed ``"collector:<id>"``; the id picks the
     collector's RNG stream and exploration rung."""
+    enable_compile_cache()
     key = jax.random.key(spec.seed)
     _kc, _km, _kp, _keval = jax.random.split(key, 4)
     try:

@@ -14,10 +14,7 @@ from repro.kernels.flash_attention import ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def attention(q, k, v, *, causal: bool = True, window: int = 0,

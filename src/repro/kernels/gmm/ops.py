@@ -15,23 +15,68 @@ member-assigned forward built on the ragged layout; its ``impl``:
   gathers there — measured in benchmarks/hotpath.py. Default on CPU
   only; GPU defaults to ``ref`` (FLOP-bound at real sizes, and the
   gathered batched matmul keeps the no-K*-overcompute invariant).
+
+Every ``pallas`` entry point is differentiable: the kernel has no
+autodiff rule of its own, so ``_pallas_gmm`` carries a ``custom_vjp``
+(the dynamics learner and MoE training take gradients through it). The
+row-wise ensemble entry points run per shard under a multi-device
+ambient mesh (``kernels/mesh.py``).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
+import jax.numpy as jnp
 
 from repro.kernels.gmm import ref
-
-
-def _backend() -> str:
-    try:
-        return jax.default_backend()
-    except RuntimeError:  # pragma: no cover
-        return "cpu"
+from repro.kernels.mesh import per_shard
 
 
 def _on_tpu() -> bool:
-    return _backend() == "tpu"
+    return jax.default_backend() == "tpu"
+
+
+# ---------------------------------------------------------------- pallas
+# Forward = the kernel. Backward, for out = lhs @ rhs[group]:
+#   d_lhs = ct @ rhs[group]^T  — the same kernel, rhs transposed, in
+#           both layouts (ragged rows keep their groups).
+#   d_rhs[g] = lhs[rows of g]^T @ ct[rows of g] — equal-group: the kernel
+#           with lhs transposed; ragged: a row-to-group mask contraction
+#           in XLA at full f32 precision (the kernel's own precision).
+# ``group_sizes`` is integer data: its cotangent is None.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _pallas_gmm(lhs, rhs, group_sizes, interpret):
+    from repro.kernels.gmm import pallas as pk
+    return pk.grouped_matmul(lhs, rhs, group_sizes, interpret=interpret)
+
+
+def _pallas_gmm_fwd(lhs, rhs, group_sizes, interpret):
+    return (_pallas_gmm(lhs, rhs, group_sizes, interpret),
+            (lhs, rhs, group_sizes))
+
+
+def _pallas_gmm_bwd(interpret, res, ct):
+    from repro.kernels.gmm import pallas as pk
+    lhs, rhs, group_sizes = res
+    rhs_t = jnp.swapaxes(rhs, 1, 2)
+    d_lhs = pk.grouped_matmul(ct, rhs_t, group_sizes, interpret=interpret)
+    if group_sizes is None:
+        d_rhs = pk.grouped_matmul(jnp.swapaxes(lhs, 1, 2), ct,
+                                  interpret=interpret)
+    else:
+        ends = jnp.cumsum(group_sizes)
+        rows = jnp.arange(lhs.shape[0])
+        in_group = ((rows[None, :] >= (ends - group_sizes)[:, None])
+                    & (rows[None, :] < ends[:, None])).astype(lhs.dtype)
+        d_rhs = jnp.einsum("gm,mk,mn->gkn", in_group, lhs, ct,
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+    return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype), None
+
+
+_pallas_gmm.defvjp(_pallas_gmm_fwd, _pallas_gmm_bwd)
 
 
 def grouped_matmul(lhs, rhs, group_sizes=None, *, impl: str | None = None,
@@ -39,9 +84,13 @@ def grouped_matmul(lhs, rhs, group_sizes=None, *, impl: str | None = None,
     if impl is None:
         impl = "pallas" if _on_tpu() else "ref"
     if impl == "pallas":
-        from repro.kernels.gmm import pallas as pk
-        return pk.grouped_matmul(lhs, rhs, group_sizes, interpret=interpret)
+        return _pallas_gmm(lhs, rhs, group_sizes, interpret)
     return ref.grouped_matmul(lhs, rhs, group_sizes)
+
+
+def _pallas_matmul(interpret):
+    return lambda lhs, rhs, group_sizes=None: _pallas_gmm(
+        lhs, rhs, group_sizes, interpret)
 
 
 def ensemble_mlp(members, x, *, impl: str | None = None,
@@ -49,8 +98,9 @@ def ensemble_mlp(members, x, *, impl: str | None = None,
     if impl is None:
         impl = "pallas" if _on_tpu() else "ref"
     if impl == "pallas":
-        from repro.kernels.gmm import pallas as pk
-        return pk.ensemble_mlp(members, x, interpret=interpret)
+        fn = lambda m, x_: ref.ensemble_mlp(
+            m, x_, matmul=_pallas_matmul(interpret))
+        return per_shard(fn, (None, 0), 1)(members, x)
     return ref.ensemble_mlp(members, x)
 
 
@@ -59,14 +109,15 @@ def ensemble_mlp_select(members, x, idx, *, impl: str | None = None,
     """Forward row b through member ``idx[b]`` only. Same output as
     ``ensemble_mlp(members, x)[idx[b], b]`` for every b."""
     if impl is None:
-        backend = _backend()
+        backend = jax.default_backend()
         impl = ("pallas" if backend == "tpu"
                 else "dense" if backend == "cpu" else "ref")
     if impl == "pallas":
-        from repro.kernels.gmm import pallas as pk
-        return pk.ensemble_mlp_select(members, x, idx, interpret=interpret)
+        fn = lambda m, x_, i: ref.ensemble_mlp_select(
+            m, x_, i, matmul=_pallas_matmul(interpret))
+        return per_shard(fn, (None, 0, 0), 0)(members, x, idx)
     if impl == "ref":
         return ref.ensemble_mlp_select(members, x, idx)
     preds = ref.ensemble_mlp(members, x)            # (K, B, D)
-    return jax.numpy.take_along_axis(
+    return jnp.take_along_axis(
         preds, idx[None, :, None], axis=0)[0]

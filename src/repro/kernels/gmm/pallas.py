@@ -14,7 +14,8 @@ Two kernels:
   tiles a group does not touch at all — zero-size groups therefore cost
   no MXU work. FLOPs scale with M, not G*M.
 
-Validated with interpret=True against ref.
+Kernels only: ``ops.py`` wraps them in a ``custom_vjp`` and builds the
+ensemble MLPs on top. Validated with interpret=True against ref.
 """
 from __future__ import annotations
 
@@ -25,7 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+# f32 products on the MXU, stated, not left to Mosaic's default: the
+# oracle's contract is f32, and on the chip it is checked at HIGHEST
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _kernel(lhs_ref, rhs_ref, out_ref, acc_scr, *, nk):
@@ -37,7 +40,8 @@ def _kernel(lhs_ref, rhs_ref, out_ref, acc_scr, *, nk):
 
     acc_scr[...] += jax.lax.dot_general(
         lhs_ref[0].astype(jnp.float32), rhs_ref[0].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (0,)), ((), ())), precision=_F32,
+        preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _done():
@@ -65,7 +69,7 @@ def _equal_grouped_matmul(lhs, rhs, *, block_m, block_n, block_k,
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, M + pm, N + pn), lhs.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -94,7 +98,8 @@ def _ragged_kernel(offs_ref, lhs_ref, rhs_ref, out_ref, acc_scr, *,
         lhs = jnp.where(mask, lhs_ref[...].astype(jnp.float32), 0.0)
         acc_scr[...] += jax.lax.dot_general(
             lhs, rhs_ref[0].astype(jnp.float32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            (((1,), (0,)), ((), ())), precision=_F32,
+            preferred_element_type=jnp.float32)
 
     @pl.when((g == ng - 1) & (k == nk - 1))
     def _done():
@@ -129,7 +134,7 @@ def _ragged_grouped_matmul(lhs, rhs, group_sizes, *, block_m, block_n,
         functools.partial(_ragged_kernel, bm=bm, ng=G, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M + pm, N + pn), lhs.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -150,23 +155,3 @@ def grouped_matmul(lhs, rhs, group_sizes=None, *, block_m: int = 128,
                                   block_n=block_n, block_k=block_k,
                                   interpret=interpret)
 
-
-def ensemble_mlp(members, x, *, interpret: bool = False):
-    """Kernel-backed K-member MLP forward (same contract as ref)."""
-    K = members["w"][0].shape[0]
-    h = jnp.broadcast_to(x[None], (K,) + x.shape)
-    n = len(members["w"])
-    for i, (w, b) in enumerate(zip(members["w"], members["b"])):
-        h = grouped_matmul(h, w, interpret=interpret) + b[:, None, :]
-        if i < n - 1:
-            h = jnp.tanh(h)
-    return h
-
-
-def ensemble_mlp_select(members, x, idx, *, interpret: bool = False):
-    """Kernel-backed sort/compute/unsort member-assigned forward (same
-    contract as ``ref.ensemble_mlp_select``): B rows of MXU work, not K*B."""
-    from repro.kernels.gmm import ref as _ref
-    return _ref.ensemble_mlp_select(
-        members, x, idx,
-        matmul=functools.partial(grouped_matmul, interpret=interpret))

@@ -46,14 +46,15 @@ def grouped_matmul(lhs, rhs, group_sizes=None):
     return out.astype(lhs.dtype)
 
 
-def ensemble_mlp(members, x):
+def ensemble_mlp(members, x, *, matmul=grouped_matmul):
     """members: {"w": [ (K,a,b) ... ], "b": [ (K,b) ... ]}; x: (B, Din)
-    shared across members. Returns (K, B, Dout). tanh hidden activations."""
+    shared across members. Returns (K, B, Dout). tanh hidden activations.
+    ``matmul`` lets the dispatcher swap in the Pallas equal-group kernel."""
     K = members["w"][0].shape[0]
     h = jnp.broadcast_to(x[None], (K,) + x.shape)
     n = len(members["w"])
     for i, (w, b) in enumerate(zip(members["w"], members["b"])):
-        h = grouped_matmul(h, w) + b[:, None, :]
+        h = matmul(h, w) + b[:, None, :]
         if i < n - 1:
             h = jnp.tanh(h)
     return h

@@ -35,19 +35,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.imag import ref
-
-
-def _backend() -> str:
-    try:
-        return jax.default_backend()
-    except RuntimeError:  # pragma: no cover
-        return "cpu"
+from repro.kernels.mesh import per_shard, row_axes
 
 
 def default_impl() -> str:
     """Backend-chosen impl: the megakernel on TPU, the XLA-fused flat
     spelling elsewhere (CPU/GPU have no Mosaic lowering)."""
-    return "pallas" if _backend() == "tpu" else "fused"
+    return "pallas" if jax.default_backend() == "tpu" else "fused"
 
 
 def sort_plan(member_idx, n_groups: int):
@@ -132,14 +126,19 @@ def fused_step(members, norm, pol, s, eps, member_idx, *,
     if impl is None:
         impl = default_impl()
     if impl == "pallas":
-        if plan is None:
-            plan = sort_plan(member_idx, members["w"][0].shape[0])
-        order, offsets = plan
-        out = _pallas_sorted(interpret, block_b, offsets,
-                             member_idx[order], members, norm, pol,
-                             s[order], eps[order])
-        unsort = lambda v: jnp.zeros_like(v).at[order].set(v)
-        return tuple(unsort(v) for v in out)
+        if row_axes() is not None:
+            plan = None         # per shard: each shard sorts its own rows
+
+        def step(members, norm, pol, s, eps, member_idx):
+            order, offsets = plan if plan is not None else sort_plan(
+                member_idx, members["w"][0].shape[0])
+            out = _pallas_sorted(interpret, block_b, offsets,
+                                 member_idx[order], members, norm, pol,
+                                 s[order], eps[order])
+            unsort = lambda v: jnp.zeros_like(v).at[order].set(v)
+            return tuple(unsort(v) for v in out)
+        return per_shard(step, (None, None, None, 0, 0, 0), 0)(
+            members, norm, pol, s, eps, member_idx)
     if impl == "fused":
         return _fused_flat(members, norm, pol, s, eps, member_idx)
     return ref.fused_step(members, norm, pol, s, eps, member_idx)

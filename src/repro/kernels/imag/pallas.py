@@ -34,7 +34,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+# f32 products on the MXU, stated, not left to Mosaic's default: the
+# oracle's contract is f32, and on the chip it is checked at HIGHEST
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _fused_kernel(offs_ref, s_ref, eps_ref, *refs, bm, n_groups, n_dyn,
@@ -57,6 +59,7 @@ def _fused_kernel(offs_ref, s_ref, eps_ref, *refs, bm, n_groups, n_dyn,
             h = jax.lax.dot_general(
                 h, w[...].astype(jnp.float32),
                 (((1,), (0,)), ((), ())),
+                precision=_F32,
                 preferred_element_type=jnp.float32) + b[...]
             if li < n_pol - 1:
                 h = jnp.tanh(h)
@@ -80,6 +83,7 @@ def _fused_kernel(offs_ref, s_ref, eps_ref, *refs, bm, n_groups, n_dyn,
             h = jax.lax.dot_general(
                 h, w[0].astype(jnp.float32),
                 (((1,), (0,)), ((), ())),
+                precision=_F32,
                 preferred_element_type=jnp.float32) + b[0]
             if li < n_dyn - 1:
                 h = jnp.tanh(h)
@@ -164,7 +168,7 @@ def fused_step_sorted(members, norm, pol, s, eps, offsets, *,
         out_shape=(jax.ShapeDtypeStruct((B + pm, obs_dim), s.dtype),
                    jax.ShapeDtypeStruct((B + pm, act_dim), s.dtype),
                    jax.ShapeDtypeStruct((B + pm, act_dim), s.dtype)),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(offsets.astype(jnp.int32), *operands)

@@ -7,10 +7,7 @@ from repro.kernels.ssd import ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def ssd(x, dt, A, B_, C, *, chunk: int = 128, initial_state=None,

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
             chunk, nc, H, P, N, G):
@@ -102,7 +100,7 @@ def ssd_chunked(x, dt, A, B_, C, *, chunk: int = 128, initial_state=None,
                                lambda b, c: (b, c, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, nc, chunk, H, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((H, P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xr, dtr, A, Br, Cr)

@@ -31,8 +31,9 @@ def build_mesh(spec: str):
     XLA_FLAGS=--xla_force_host_platform_device_count=N on CPU)."""
     if spec == "none":
         return None
+    from repro.launch.mesh import make_mesh
     n = jax.device_count() if spec == "auto" else int(spec)
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def run_mbrl(args):
@@ -246,6 +247,8 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.task == "mbrl":
         if args.connect:
             run_join(args)

@@ -21,6 +21,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.mesh import on_mesh
 from repro.mbrl import dynamics as DYN
 from repro.mbrl import policy as PI
 from repro.mbrl import ppo as PPO
@@ -120,6 +121,11 @@ class _MeshMixin:
         # drop any traces compiled before the mesh was known
         self._improve = jax.jit(self._improve_impl)
 
+    def _mesh_scope(self):
+        """Trace-time scope: kernels run per shard of the sub-mesh."""
+        return on_mesh(None if self._batch_sharding is None
+                       else self._batch_sharding.mesh)
+
     def _shard_batch(self, x):
         if self._batch_sharding is None:
             return x
@@ -159,9 +165,10 @@ class MEAlgo(_MeshMixin):
         # shard imagined starts over the policy sub-mesh: the rollout scan
         # carries the batch dim, so imagination runs data-parallel
         s0 = self._shard_batch(self.init_state_fn(k0, cfg.imagine_batch))
-        obs, pre, rew = _rollout_with_logp(
-            model_params, state["policy"], s0, k1, cfg.imagine_horizon,
-            self.reward_fn, self.predict_fn)
+        with self._mesh_scope():
+            obs, pre, rew = _rollout_with_logp(
+                model_params, state["policy"], s0, k1, cfg.imagine_horizon,
+                self.reward_fn, self.predict_fn)
         # TRPO/PPO statistics (advantages, Fisher-vector products, line
         # search) computed over the sharded flat batch
         batch = self._shard_batch(_flat_batch(obs, pre, rew, cfg.gamma))

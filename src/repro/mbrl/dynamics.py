@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.kernels.gmm import ops as gmm_ops
 from repro.kernels.imag import ops as imag_ops
+from repro.kernels.mesh import on_mesh, row_axes
 from repro.mbrl import policy as PI
 from repro.optim.optimizers import adam, apply_updates
 from repro.utils.jit_stats import trace_counted
@@ -270,20 +271,26 @@ def make_ring_trainer(cfg: EnsembleConfig, capacity: int,
         shard_batch = lambda x: jax.lax.with_sharding_constraint(
             x, batch_sharding)
 
+    # the ensemble kernels run per shard of the owning sub-mesh
+    mesh = None if batch_sharding is None else batch_sharding.mesh
+
     def _train_epoch(params, opt_state, data, size, key):
         idx = jax.random.randint(key, (nb, bs), 0,
                                  jnp.maximum(size, 1))
         # one pass over the VALID region per epoch (like the legacy
         # trainer), not over the whole capacity grid
         n_active = jnp.clip(size // bs, 1, nb)
-        return _sgd_epoch_scan(opt, params, opt_state, data["obs"],
-                               data["act"], data["next_obs"], idx,
-                               n_active=n_active, shard_batch=shard_batch)
+        with on_mesh(mesh):
+            return _sgd_epoch_scan(opt, params, opt_state, data["obs"],
+                                   data["act"], data["next_obs"], idx,
+                                   n_active=n_active,
+                                   shard_batch=shard_batch)
 
     def _val_loss(params, data, size):
         w = jnp.arange(data["obs"].shape[0]) < size
-        return masked_mse_loss(params, data["obs"], data["act"],
-                               data["next_obs"], w)
+        with on_mesh(mesh):
+            return masked_mse_loss(params, data["obs"], data["act"],
+                                   data["next_obs"], w)
 
     def _update_norm(data, size):
         return masked_norm_stats(data["obs"], data["act"],
@@ -315,8 +322,9 @@ def horizon_plan(params, member_idx):
     """Sort/unsort plans for a whole horizon of member assignments
     ((H, B) int), for threading through a rollout scan — or None when the
     backend's fused impl doesn't sort (the flat XLA path is
-    row-order-blind, so no plan is ever computed on CPU/GPU)."""
-    if imag_ops.default_impl() != "pallas":
+    row-order-blind, so no plan is ever computed on CPU/GPU), or sorts
+    per shard of a multi-device ambient mesh (``kernels/mesh.py``)."""
+    if imag_ops.default_impl() != "pallas" or row_axes() is not None:
         return None
     return imag_ops.sort_plan(member_idx, n_members(params))
 

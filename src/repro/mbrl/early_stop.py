@@ -24,5 +24,8 @@ class EMAEarlyStop:
             return False
         if self.enabled and val_loss > self.ema:
             self.stopped = True
-        self.ema = self.weight * self.ema + (1 - self.weight) * val_loss
+        # the EMA in increment form: it stays between val_loss and the old
+        # EMA after rounding, so a plateau (val_loss == ema) cannot drift
+        # the EMA below it and fake an increase on the next epoch
+        self.ema = self.ema + (1 - self.weight) * (val_loss - self.ema)
         return self.stopped

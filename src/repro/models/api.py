@@ -108,17 +108,8 @@ class StepBundle:
 def _shard_map(fn, mesh, in_specs, out_specs, check_vma=False):
     """check_vma=True enables replication tracking, which turns psum
     transposes into communication-free pbroadcasts (§Perf iteration 1)."""
-    try:
-        # AttributeError: jax<0.5 has no top-level shard_map at all
-        # (jax._src.deprecations raises instead of returning the symbol);
-        # TypeError: intermediate versions expose it under the older
-        # check_rep kwarg name only.
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def _ns(mesh, specs):

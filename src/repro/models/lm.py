@@ -362,16 +362,10 @@ def make_train_step(cfg: ModelConfig, ctx: ShardCtx, opt: Optimizer,
     flat_specs = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     dp_all = tuple(ctx.dp_axes)
 
-    def _axis_size(ax):
-        try:
-            return jax.lax.axis_size(ax)
-        except AttributeError:      # jax<0.6: psum of 1 == axis size
-            return jax.lax.psum(1, ax)      # (constant-folded by XLA)
-
     def _dp_idx():
         idx = jnp.zeros((), jnp.int32)
         for ax in dp_all:
-            idx = idx * _axis_size(ax) + jax.lax.axis_index(ax)
+            idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         return idx
 
     def z_slice(tree):

@@ -19,6 +19,7 @@ from repro.core.servers import ParameterServer
 from repro.launch.mesh import make_smoke_mesh
 from repro.models import api
 from repro.serve import WorldModelServer
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -31,6 +32,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=True)
     key_w, key_w2 = jax.random.split(jax.random.key(args.seed))

@@ -1,9 +1,17 @@
 import importlib.util
+import os
 import signal
 import warnings
 
+# The suite never writes JAX's persistent compilation cache: entry points
+# the tests drive (procs-mode children included, which inherit this
+# environment) enable it, and this keeps it off for all of them.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
 import jax
 import pytest
+
+jax.config.update("jax_enable_compilation_cache", False)
 
 warnings.filterwarnings("ignore", category=DeprecationWarning)
 

@@ -216,7 +216,6 @@ def test_ensemble_mlp_select_impls_agree():
     """dense (compute-all-and-select), ref (sort/ragged/unsort) and
     pallas-interpret must produce the same per-row member outputs."""
     from repro.kernels.gmm import ops as gmm_ops
-    from repro.kernels.gmm import pallas as gmm_pallas
     K_, B, Din, Dh, Dout = 4, 33, 7, 24, 5
     members = {
         "w": [rand((K_, Din, Dh), jnp.float32, 33),
@@ -236,8 +235,8 @@ def test_ensemble_mlp_select_impls_agree():
         ref_out = gmm_ops.ensemble_mlp_select(members, x, idx, impl="ref")
         np.testing.assert_allclose(np.asarray(ref_out), np.asarray(exp),
                                    atol=1e-5, rtol=1e-5)
-        pk_out = gmm_pallas.ensemble_mlp_select(members, x, idx,
-                                                interpret=True)
+        pk_out = gmm_ops.ensemble_mlp_select(members, x, idx,
+                                             impl="pallas", interpret=True)
         np.testing.assert_allclose(np.asarray(pk_out), np.asarray(exp),
                                    atol=1e-4, rtol=1e-4)
 

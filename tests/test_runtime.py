@@ -1,5 +1,8 @@
 """Async framework behaviour tests (the paper's core claims, miniaturised)."""
+import pathlib
+
 import jax
+import pytest
 
 from repro.core import (AsyncTrainer, PartialAsyncDataPolicy,
                         PartialAsyncModelPolicy, RunConfig,
@@ -92,6 +95,62 @@ def test_threads_trace_times_relative_and_monotonic():
     times = [r["time"] for r in trace]
     assert all(0.0 <= t < 600.0 for t in times), times
     assert times == sorted(times), times
+
+
+@pytest.mark.parametrize("worker,cfg", [
+    ("model_worker", {"min_final_model_version": 1}),
+    ("policy_worker", {"min_final_policy_version": 2}),
+])
+def test_threads_learner_failure_fails_the_run(worker, cfg):
+    """A learner thread that raises must fail run(), not leave a run that
+    'passes' at version 0. The version floor keeps the run waiting on
+    that learner, so its error is always the one that ends it."""
+    env = make_env("pendulum")
+    ens, algo = build(env)
+    tr = AsyncTrainer(env, ens, algo,
+                      RunConfig(total_trajs=3, seed=0, **cfg),
+                      mode="threads")
+
+    def boom():
+        raise ValueError("learner exploded")
+    setattr(getattr(tr, worker), "step", boom)
+    with pytest.raises(RuntimeError, match="learner failed") as info:
+        tr.run()
+    assert isinstance(info.value.__cause__, ValueError)
+
+
+def test_procs_mode_refuses_tpu(monkeypatch):
+    """On a chip the parent holds the device before any child spawns, so
+    procs mode must refuse up front and point at threads mode."""
+    env = make_env("pendulum")
+    ens = EnsembleConfig(env.obs_dim, env.act_dim, hidden=32, n_models=2)
+    pol = PolicyConfig(env.obs_dim, env.act_dim, hidden=16)
+    acfg = AlgoConfig(algo="me-trpo", imagine_batch=16, imagine_horizon=15,
+                      n_models=2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match='mode="threads"'):
+        AsyncTrainer(env, ens, None, RunConfig(total_trajs=3),
+                     mode="procs", algo_cfg=acfg, pol_cfg=pol)
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_dir_choice(env_dir, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; unset, the
+    cache goes to the fixed .jax_cache/ at the checkout root."""
+    from repro.utils import compile_cache as CC
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env_dir:
+        monkeypatch.setenv(CC.ENV_VAR, str(tmp_path))
+        assert CC.enable_compile_cache() == tmp_path
+        assert updates == []
+    else:
+        monkeypatch.delenv(CC.ENV_VAR, raising=False)
+        root = pathlib.Path(__file__).resolve().parent.parent
+        assert CC.enable_compile_cache() == root / ".jax_cache"
+        assert updates == [("jax_compilation_cache_dir",
+                            str(root / ".jax_cache"))]
 
 
 def test_run_config_not_shared_between_trainers():
