@@ -33,6 +33,7 @@ All engines record an eval trace: list of dicts
 from __future__ import annotations
 
 import dataclasses
+import gc
 import queue as _queue
 import tempfile
 import threading
@@ -44,6 +45,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.roles import RoleSplit, split_roles
 from repro.core.servers import (DataServer, ParameterServer, ProcDataServer,
@@ -151,14 +153,35 @@ def _eval_fn(env, eval_rollouts: int):
     cache_key = (env, eval_rollouts)
     fn = _EVAL_CACHE.pop(cache_key, None)   # pop + reinsert = LRU touch
     if fn is None:
-        fn = jax.jit(lambda p, k: jnp.mean(jax.vmap(
-            lambda kk: env.rollout(
-                kk, lambda pp, s, k2: PI.deterministic_action(pp, s),
-                p)["rew"].sum())(jax.random.split(k, eval_rollouts))))
+        def eval_return(p, k):          # its executions: jit_eval_return
+            return jnp.mean(jax.vmap(
+                lambda kk: env.rollout(
+                    kk, lambda pp, s, k2: PI.deterministic_action(pp, s),
+                    p)["rew"].sum())(jax.random.split(k, eval_rollouts)))
+        fn = jax.jit(eval_return)
     _EVAL_CACHE[cache_key] = fn
     while len(_EVAL_CACHE) > _EVAL_CACHE_MAX:   # dicts iterate insertion-
         del _EVAL_CACHE[next(iter(_EVAL_CACHE))]    # order: oldest first
     return fn
+
+
+def _gc_span_hook():
+    """A ``gc.callbacks`` hook that records each full (generation-2)
+    collection as a span named ``gc``, from its start to its stop, on
+    the thread that collects: a collection holds the interpreter lock,
+    so it stops every other thread of the engine while it runs."""
+    open_spans = []
+
+    def hook(phase, info):
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            span = TraceAnnotation("gc")
+            span.__enter__()
+            open_spans.append(span)
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
+    return hook
 
 
 class _Recorder:
@@ -572,12 +595,15 @@ class AsyncTrainer:
                     # emulate the robot's control frequency: a trajectory
                     # occupies `dur` seconds of real time regardless of
                     # how fast the simulated rollout computes
-                    time.sleep(max(dur - (time.monotonic() - t_step), 0.0))
+                    with TraceAnnotation("collector.pace"):
+                        time.sleep(max(dur - (time.monotonic() - t_step),
+                                       0.0))
 
         def model_loop():
             while not stop.is_set():
                 if self.model_worker.step() is None:
-                    time.sleep(0.002)
+                    with TraceAnnotation("model.idle"):
+                        time.sleep(0.002)
 
         def policy_loop():
             n = 0
@@ -585,29 +611,38 @@ class AsyncTrainer:
                 if self.policy_worker.step():
                     n += 1
                     if n % rc.eval_every_policy_steps == 0:
-                        self._keval, k = jax.random.split(self._keval)
-                        self.recorder.record(
-                            time.monotonic() - t0, ds.total_pushed,
-                            self.policy_worker.state["policy"], k)
+                        # the policy thread's one wait on the device
+                        with TraceAnnotation("policy.eval"):
+                            self._keval, k = jax.random.split(self._keval)
+                            self.recorder.record(
+                                time.monotonic() - t0, ds.total_pushed,
+                                self.policy_worker.state["policy"], k)
                 else:
-                    time.sleep(0.002)
+                    with TraceAnnotation("policy.idle"):
+                        time.sleep(0.002)
 
         collect_threads = [
             guarded(f"collector {w.collector_id}", collect_loop, w)
             for w in self.collectors]
         learner_threads = [guarded("model learner", model_loop),
                            guarded("policy learner", policy_loop)]
-        for th in collect_threads + learner_threads:
-            th.start()
-        for th in collect_threads:  # every claimed slot has been pushed
-            th.join()               # once the whole fleet exits
-        while not stop.is_set() and (
-                self.model_server.version < rc.min_final_model_version
-                or self.policy_server.version < rc.min_final_policy_version):
-            time.sleep(0.01)        # learners still owe their versions
-        stop.set()
-        for th in learner_threads:
-            th.join(timeout=10)
+        gc_hook = _gc_span_hook()
+        gc.callbacks.append(gc_hook)
+        try:
+            for th in collect_threads + learner_threads:
+                th.start()
+            for th in collect_threads:  # every claimed slot has been
+                th.join()               # pushed once the whole fleet exits
+            while not stop.is_set() and (
+                    self.model_server.version < rc.min_final_model_version
+                    or self.policy_server.version
+                    < rc.min_final_policy_version):
+                time.sleep(0.01)        # learners still owe their versions
+            stop.set()
+            for th in learner_threads:
+                th.join(timeout=10)
+        finally:
+            gc.callbacks.remove(gc_hook)
         if errors:
             role, err = errors[0]
             raise RuntimeError(

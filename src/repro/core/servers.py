@@ -57,6 +57,7 @@ from typing import (Any, Dict, List, Optional, Protocol, Tuple,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # NOTE: on backends without buffer aliasing (CPU) the donated jits below
 # warn once at compile that donation fell back to a copy — that is
@@ -171,13 +172,14 @@ class ParameterServer:
         return None
 
     def push(self, value) -> int:
-        snap = self._snapshot(value)    # copy outside the lock
-        src = self._leaf_sharding(snap)
-        with self._lock:
-            self._value = snap
-            self._src_sharding = src
-            self._version += 1
-            return self._version
+        with TraceAnnotation("param.push"):
+            snap = self._snapshot(value)    # copy outside the lock
+            src = self._leaf_sharding(snap)
+            with self._lock:
+                self._value = snap
+                self._src_sharding = src
+                self._version += 1
+                return self._version
 
     def pull(self):
         """Returns (value, version); value is None until the first push."""
@@ -200,11 +202,12 @@ class ParameterServer:
             if self._version == version or self._value is None:
                 return None, self._version
             value, ver, src = self._value, self._version, self._src_sharding
-        if sharding is not None and src != sharding:
-            # outside the lock: value is an immutable snapshot; one
-            # pytree-aware device_put batches all leaf transfers
-            value = jax.device_put(value, sharding)
-        return value, ver
+        with TraceAnnotation("param.pull"):
+            if sharding is not None and src != sharding:
+                # outside the lock: value is an immutable snapshot; one
+                # pytree-aware device_put batches all leaf transfers
+                value = jax.device_put(value, sharding)
+            return value, ver
 
     def pull_host(self):
         """Host-materialised pull for checkpoint / serving boundaries —
@@ -253,7 +256,7 @@ class DataServer:
         self._inflight: Dict[int, int] = {}
 
     def push(self, traj, *, collector_id: int = 0) -> int:
-        with self._lock:
+        with TraceAnnotation("data.push"), self._lock:
             self._items.append(traj)
             self._total += 1
             self._dec_inflight(collector_id, 1)
@@ -266,12 +269,13 @@ class DataServer:
         OUTSIDE the lock, then appended and counted atomically, so
         ``total_pushed`` moves by n in one step and interleaved
         producers stay exact."""
-        lanes = [{k: v[i] for k, v in batch.items()} for i in range(n)]
-        with self._lock:
-            self._items.extend(lanes)
-            self._total += n
-            self._dec_inflight(collector_id, n)
-            return self._total
+        with TraceAnnotation("data.push"):
+            lanes = [{k: v[i] for k, v in batch.items()} for i in range(n)]
+            with self._lock:
+                self._items.extend(lanes)
+                self._total += n
+                self._dec_inflight(collector_id, n)
+                return self._total
 
     def set_target(self, total: int) -> None:
         """Arm the stopping criterion: from now on ``try_claim`` grants

@@ -34,6 +34,13 @@ its own jax backend — and talks only through the IPC servers in
 return host arrays; the pull paths below re-home them onto the worker's
 device exactly once per version change, so step loops stay
 device-resident in every mode.
+
+Spans: each worker names what its thread is doing with
+``jax.profiler.TraceAnnotation`` (``collector.step``, ``model.epoch``,
+``policy.improve``, ...; the servers add ``data.push``, ``param.push``
+and ``param.pull``). They land in a profiler trace on the device's
+clock; with no profiler session a span costs under a microsecond. The
+list is in README.md, "Tracing a run".
 """
 from __future__ import annotations
 
@@ -43,6 +50,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import roles as ROLES
 from repro.core.servers import DataServer, ParameterServer, ReplayBuffer
@@ -276,11 +284,12 @@ class DataCollectionWorker:
         True once a policy is available — procs-mode collectors spin on
         this during warmup so a claimed collection slot is always
         fulfilled by the following ``step``."""
-        fresh, self._policy_ver = self.policy_server.pull_if_newer(
-            self._policy_ver, sharding=self._sharding)
-        if fresh is not None:
-            self._policy_cache = _to_device(fresh)
-        return self._policy_cache is not None
+        with TraceAnnotation("collector.pull"):
+            fresh, self._policy_ver = self.policy_server.pull_if_newer(
+                self._policy_ver, sharding=self._sharding)
+            if fresh is not None:
+                self._policy_cache = _to_device(fresh)
+            return self._policy_cache is not None
 
     def step(self, n: Optional[int] = None) -> Optional[float]:
         """One batch of ``n`` trajectories (default: the worker's full
@@ -294,24 +303,30 @@ class DataCollectionWorker:
         at the very end of a run). The batch simulates n robots in
         PARALLEL, so the robot-time duration is one trajectory's
         regardless of n."""
-        if not self.poll_policy():                      # Pull (gated)
-            return None
-        g = self.envs_per_step if n is None else int(n)
-        # ONE key split per step whatever g is: the B=1 stream is the
-        # pre-farm stream, and lanes derive inside the batch program
-        self._key, k = jax.random.split(self._key)
-        if g == 1:
-            traj = self._rollout(self._policy_cache, k)     # Step
-            self.data_server.push(traj,
-                                  collector_id=self.collector_id)  # Push
-        else:
-            fn = (self._rollout_batch if g == self.envs_per_step
-                  else _rollout_batch_jit(self.env, self.noise_scale, g))
-            batch = fn(self._policy_cache, k)               # Step (farm)
-            self.data_server.push_batch(
-                batch, g, collector_id=self.collector_id)   # Push
-        self.collected += g
-        return (self.env.horizon * self.env.dt) / self.speed
+        with TraceAnnotation("collector.step"):
+            if not self.poll_policy():                  # Pull (gated)
+                return None
+            g = self.envs_per_step if n is None else int(n)
+            with TraceAnnotation("collector.rollout"):
+                # ONE key split per step whatever g is: the B=1 stream is
+                # the pre-farm stream, and lanes derive inside the batch
+                # program
+                self._key, k = jax.random.split(self._key)
+                if g == 1:
+                    out = self._rollout(self._policy_cache, k)  # Step
+                else:
+                    fn = (self._rollout_batch if g == self.envs_per_step
+                          else _rollout_batch_jit(self.env,
+                                                  self.noise_scale, g))
+                    out = fn(self._policy_cache, k)     # Step (farm)
+            if g == 1:
+                self.data_server.push(out,
+                                      collector_id=self.collector_id)  # Push
+            else:
+                self.data_server.push_batch(
+                    out, g, collector_id=self.collector_id)     # Push
+            self.collected += g
+            return (self.env.horizon * self.env.dt) / self.speed
 
 
 class ModelLearningWorker:
@@ -387,37 +402,44 @@ class ModelLearningWorker:
     def _refresh_data(self) -> bool:
         new = self.data_server.drain()                  # Pull (move all)
         if new:
-            self._ensure_trainer(new[0])
-            self.buffer.extend(new)
-            self._have_data = True
-            self.stopper.reset()                        # §4: resume training
+            with TraceAnnotation("ring.ingest"):
+                self._ensure_trainer(new[0])
+                self.buffer.extend(new)
+                self._have_data = True
+                self.stopper.reset()                    # §4: resume training
         return bool(new)
 
     def step(self) -> Optional[float]:
         """One epoch; returns None when idle (no data / early-stopped)."""
-        self._refresh_data()
-        if not self._have_data or self.buffer.total_seen < self.min_trajs:
-            return None
-        if self.stopper.stopped:
-            return None
-        data, size = self.buffer.train_view()
-        self.params = {**self.params,
-                       "norm": self._update_norm(data, size)}
-        self._key, k = jax.random.split(self._key)
-        self.params, self.opt_state, tr_loss = self._train_epoch(
-            self.params, self.opt_state, data, size, k)
-        vdata, vsize = self.buffer.val_view()
-        if vsize == 0:
-            # no held-out traj yet: validate on a val-ring-SHAPED slice
-            # of the train ring, so _val_loss still compiles only once
-            vcap = self.buffer.val_capacity
-            vdata = {k: v[:vcap] for k, v in data.items()}
-            vsize = min(size, vcap)
-        vloss = float(self._val_loss(self.params, vdata, vsize))
-        self.stopper.update(vloss)
-        self.epochs += 1
-        self.model_server.push(self.params)             # Push
-        return vloss
+        with TraceAnnotation("model.step"):
+            self._refresh_data()
+            if (not self._have_data
+                    or self.buffer.total_seen < self.min_trajs):
+                return None
+            if self.stopper.stopped:
+                return None
+            data, size = self.buffer.train_view()
+            with TraceAnnotation("model.epoch"):
+                self.params = {**self.params,
+                               "norm": self._update_norm(data, size)}
+                self._key, k = jax.random.split(self._key)
+                self.params, self.opt_state, tr_loss = self._train_epoch(
+                    self.params, self.opt_state, data, size, k)
+            vdata, vsize = self.buffer.val_view()
+            if vsize == 0:
+                # no held-out traj yet: validate on a val-ring-SHAPED
+                # slice of the train ring, so _val_loss still compiles
+                # only once
+                vcap = self.buffer.val_capacity
+                vdata = {k: v[:vcap] for k, v in data.items()}
+                vsize = min(size, vcap)
+            # the model thread's one wait on the device
+            with TraceAnnotation("model.val_wait"):
+                vloss = float(self._val_loss(self.params, vdata, vsize))
+            self.stopper.update(vloss)
+            self.epochs += 1
+            self.model_server.push(self.params)         # Push
+            return vloss
 
 
 class PolicyImprovementWorker:
@@ -462,18 +484,20 @@ class PolicyImprovementWorker:
         return jit_cache_size(fn) if fn is not None else -1
 
     def step(self) -> bool:
-        fresh, self._model_ver = self.model_server.pull_if_newer(
-            self._model_ver, sharding=self._repl)       # Pull (gated)
-        if fresh is not None:
-            self._model_cache = _to_device(fresh)
-        if self._model_cache is None:
-            return False
-        self._key, k = jax.random.split(self._key)
-        self.state, info = self.algo.improve(self.state, self._model_cache,
-                                             k)
-        self.steps += 1
-        self.policy_server.push(self.state["policy"])   # Push
-        return True
+        with TraceAnnotation("policy.step"):
+            fresh, self._model_ver = self.model_server.pull_if_newer(
+                self._model_ver, sharding=self._repl)   # Pull (gated)
+            if fresh is not None:
+                self._model_cache = _to_device(fresh)
+            if self._model_cache is None:
+                return False
+            with TraceAnnotation("policy.improve"):
+                self._key, k = jax.random.split(self._key)
+                self.state, info = self.algo.improve(
+                    self.state, self._model_cache, k)
+            self.steps += 1
+            self.policy_server.push(self.state["policy"])   # Push
+            return True
 
 
 # --------------------------------------------------------------- procs mode

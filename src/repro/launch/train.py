@@ -19,6 +19,7 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from contextlib import nullcontext
 
 import jax
 import jax.numpy as jnp
@@ -92,7 +93,16 @@ def run_mbrl(args):
     }
     tr = engines[args.engine]()
     t0 = time.perf_counter()  # monotonic: an NTP step must not skew this
-    trace = tr.run()
+    profile = nullcontext()
+    if args.profile_dir:
+        # the program's spans and the device's executions, on one clock
+        # (README, "Tracing a run")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0        # spans and device events only
+        profile = jax.profiler.trace(args.profile_dir,
+                                     profiler_options=opts)
+    with profile:
+        trace = tr.run()
     out = {"engine": args.engine, "algo": args.algo, "env": args.env,
            "real_seconds": round(time.perf_counter() - t0, 1),
            "trace": trace}
@@ -239,6 +249,10 @@ def main():
                     help="procs mode: where the supervisor snapshots "
                          "params+versions (default: fresh temp dir)")
     ap.add_argument("--out", default=None)
+    ap.add_argument("--profile-dir", default=None,
+                    help="record a profiler trace of the run into DIR "
+                         "(jax.profiler.trace): the engine's spans on "
+                         "the device's clock; threads and event modes")
     # lm
     ap.add_argument("--arch", default="glm4-9b")
     ap.add_argument("--reduced", action="store_true")
