@@ -9,7 +9,9 @@ VMEM (nothing spills to HBM between layers), and only the rows assigned
 to that member are accumulated into the output.
 
 Layout follows the ragged ``gmm`` kernel: rows arrive PRE-SORTED by
-member, cumulative group offsets ride in via scalar prefetch
+member, as one ``[s | eps]`` slab, and leave as one ``[s2 | a | pre]``
+slab in the same order, so the dispatcher moves each way with a single
+row gather; cumulative group offsets ride in via scalar prefetch
 (``PrefetchScalarGridSpec``), boundary tiles a member only partially
 covers are row-masked with a ``broadcasted_iota`` compare, and tiles a
 member does not touch at all are skipped with ``pl.when`` — zero-size
@@ -18,7 +20,9 @@ groups (members no row sampled) cost no MXU work.
 Grid: ``(B/bm, K)`` with the member dimension innermost and
 ``arbitrary`` (sequential), so the per-block scratches written at
 ``g == 0`` (normalised input, zeroed accumulator) stay live across the
-member sweep and the next state is emitted at ``g == K - 1``.
+member sweep. The output block, indexed by the row block alone, stays
+resident too: ``a`` and ``pre`` are written into it at ``g == 0`` and
+the next state at ``g == K - 1``.
 
 Validated with ``interpret=True`` against ``ref`` (the pure-jnp oracle);
 on real TPUs the tiny MBRL feature dims (obs+act < 8) would be padded to
@@ -39,14 +43,14 @@ from jax.experimental.pallas import tpu as pltpu
 _F32 = jax.lax.Precision.HIGHEST
 
 
-def _fused_kernel(offs_ref, s_ref, eps_ref, *refs, bm, n_groups, n_dyn,
+def _fused_kernel(offs_ref, x_ref, *refs, bm, n_groups, obs_dim, n_dyn,
                   n_pol):
     dyn_w = refs[:n_dyn]
     dyn_b = refs[n_dyn:2 * n_dyn]
     pol_w = refs[2 * n_dyn:2 * n_dyn + n_pol]
     pol_b = refs[2 * n_dyn + n_pol:2 * n_dyn + 2 * n_pol]
     (log_std_ref, mu_in_ref, sig_in_ref, mu_out_ref, sig_out_ref,
-     s2_ref, a_ref, pre_ref, xn_scr, acc_scr) = refs[2 * (n_dyn + n_pol):]
+     out_ref, xn_scr, acc_scr) = refs[2 * (n_dyn + n_pol):]
 
     i = pl.program_id(0)
     g = pl.program_id(1)
@@ -54,7 +58,9 @@ def _fused_kernel(offs_ref, s_ref, eps_ref, *refs, bm, n_groups, n_dyn,
     @pl.when(g == 0)
     def _policy_head():
         # policy MLP + reparameterised sample, all in VMEM
-        h = s_ref[...].astype(jnp.float32)
+        x = x_ref[...].astype(jnp.float32)
+        s, eps = x[:, :obs_dim], x[:, obs_dim:]
+        h = s
         for li, (w, b) in enumerate(zip(pol_w, pol_b)):
             h = jax.lax.dot_general(
                 h, w[...].astype(jnp.float32),
@@ -63,13 +69,12 @@ def _fused_kernel(offs_ref, s_ref, eps_ref, *refs, bm, n_groups, n_dyn,
                 preferred_element_type=jnp.float32) + b[...]
             if li < n_pol - 1:
                 h = jnp.tanh(h)
-        pre = h + jnp.exp(log_std_ref[...].astype(jnp.float32)) \
-            * eps_ref[...].astype(jnp.float32)
+        pre = h + jnp.exp(log_std_ref[...].astype(jnp.float32)) * eps
         a = jnp.tanh(pre)
-        pre_ref[...] = pre.astype(pre_ref.dtype)
-        a_ref[...] = a.astype(a_ref.dtype)
-        x = jnp.concatenate([s_ref[...].astype(jnp.float32), a], axis=1)
-        xn_scr[...] = (x - mu_in_ref[...]) / sig_in_ref[...]
+        out_ref[:, obs_dim:] = jnp.concatenate([a, pre], axis=1).astype(
+            out_ref.dtype)
+        xn = jnp.concatenate([s, a], axis=1)
+        xn_scr[...] = (xn - mu_in_ref[...]) / sig_in_ref[...]
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     start, end = offs_ref[g], offs_ref[g + 1]
@@ -93,34 +98,36 @@ def _fused_kernel(offs_ref, s_ref, eps_ref, *refs, bm, n_groups, n_dyn,
 
     @pl.when(g == n_groups - 1)
     def _emit_next_state():
-        s2 = s_ref[...].astype(jnp.float32) \
+        s2 = x_ref[:, :obs_dim].astype(jnp.float32) \
             + acc_scr[...] * sig_out_ref[...] + mu_out_ref[...]
-        s2_ref[...] = s2.astype(s2_ref.dtype)
+        out_ref[:, :obs_dim] = s2.astype(out_ref.dtype)
 
 
-def fused_step_sorted(members, norm, pol, s, eps, offsets, *,
+def fused_step_sorted(members, norm, pol, x, offsets, *,
                       block_b: int = 128, interpret: bool = False):
     """Fused step on rows PRE-SORTED by member.
 
-    s: (B, obs); eps: (B, act); offsets: (K+1,) int32 cumulative group
-    offsets (``offsets[g]..offsets[g+1]`` are member g's rows). Returns
-    ``(s2, a, pre)`` in the same sorted order; the dispatcher owns the
-    sort/unsort (hoisted out of the rollout scan).
+    x: (B, obs+act) rows ``[s | eps]``; offsets: (K+1,) int32 cumulative
+    group offsets (``offsets[g]..offsets[g+1]`` are member g's rows).
+    Returns the (B, obs+2*act) rows ``[s2 | a | pre]`` in the same sorted
+    order; the dispatcher owns the sort/unsort (hoisted out of the
+    rollout scan), one row gather each way.
     """
-    B, obs_dim = s.shape
-    act_dim = eps.shape[1]
+    B, din = x.shape
+    obs_dim = norm["mu_out"].shape[-1]
+    act_dim = din - obs_dim
+    dout = obs_dim + 2 * act_dim
     K = members["w"][0].shape[0]
     n_dyn, n_pol = len(members["w"]), len(pol["w"])
     bm = min(block_b, B)
     pm = (-B) % bm
     nm = (B + pm) // bm
-    sp = jnp.pad(s, ((0, pm), (0, 0)))
-    ep = jnp.pad(eps, ((0, pm), (0, 0)))
+    xp = jnp.pad(x, ((0, pm), (0, 0)))
 
     # 1-D params ride in as (1, dim) blocks (TPU refs want >= 2-D)
     row = lambda v: v.reshape(1, -1)
     operands = (
-        [sp, ep]
+        [xp]
         + list(members["w"])                       # (K, din, dout) each
         + [b.reshape(K, 1, -1) for b in members["b"]]
         + list(pol["w"])                           # (din, dout) each
@@ -138,38 +145,29 @@ def fused_step_sorted(members, norm, pol, s, eps, offsets, *,
                             lambda i, g, offs: (g,) + (0,) * (len(shape) - 1))
 
     in_specs = (
-        [pl.BlockSpec((bm, obs_dim), lambda i, g, offs: (i, 0)),
-         pl.BlockSpec((bm, act_dim), lambda i, g, offs: (i, 0))]
+        [pl.BlockSpec((bm, din), lambda i, g, offs: (i, 0))]
         + [member_block(w.shape) for w in members["w"]]
         + [member_block((K, 1, b.shape[-1])) for b in members["b"]]
         + [fixed(w.shape) for w in pol["w"]]
         + [fixed((1, b.shape[-1])) for b in pol["b"]]
-        + [fixed((1, act_dim)), fixed((1, obs_dim + act_dim)),
-           fixed((1, obs_dim + act_dim)), fixed((1, obs_dim)),
-           fixed((1, obs_dim))]
-    )
-    out_specs = (
-        pl.BlockSpec((bm, obs_dim), lambda i, g, offs: (i, 0)),
-        pl.BlockSpec((bm, act_dim), lambda i, g, offs: (i, 0)),
-        pl.BlockSpec((bm, act_dim), lambda i, g, offs: (i, 0)),
+        + [fixed((1, act_dim)), fixed((1, din)), fixed((1, din)),
+           fixed((1, obs_dim)), fixed((1, obs_dim))]
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nm, K),
         in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((bm, obs_dim + act_dim), jnp.float32),
+        out_specs=pl.BlockSpec((bm, dout), lambda i, g, offs: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((bm, din), jnp.float32),
                         pltpu.VMEM((bm, obs_dim), jnp.float32)],
     )
-    s2, a, pre = pl.pallas_call(
-        functools.partial(_fused_kernel, bm=bm, n_groups=K, n_dyn=n_dyn,
-                          n_pol=n_pol),
+    out = pl.pallas_call(
+        functools.partial(_fused_kernel, bm=bm, n_groups=K,
+                          obs_dim=obs_dim, n_dyn=n_dyn, n_pol=n_pol),
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((B + pm, obs_dim), s.dtype),
-                   jax.ShapeDtypeStruct((B + pm, act_dim), s.dtype),
-                   jax.ShapeDtypeStruct((B + pm, act_dim), s.dtype)),
+        out_shape=jax.ShapeDtypeStruct((B + pm, dout), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(offsets.astype(jnp.int32), *operands)
-    return s2[:B], a[:B], pre[:B]
+    return out[:B]

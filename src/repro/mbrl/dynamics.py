@@ -320,7 +320,9 @@ def step_fused(params, policy_params, s, eps, member_idx, *, impl=None,
 
 def horizon_plan(params, member_idx):
     """Sort/unsort plans for a whole horizon of member assignments
-    ((H, B) int), for threading through a rollout scan — or None when the
+    ((H, B) int), for threading through a rollout scan: per step the
+    member order, the group offsets and the inverse order, so each step
+    moves its rows by one gather in and one gather out. None when the
     backend's fused impl doesn't sort (the flat XLA path is
     row-order-blind, so no plan is ever computed on CPU/GPU), or sorts
     per shard of a multi-device ambient mesh (``kernels/mesh.py``)."""

@@ -197,44 +197,121 @@ IMAG_CASES = [
 ]
 
 
-@pytest.mark.parametrize("case", IMAG_CASES)
-def test_imag_ops_impls_vs_oracle(case):
+@pytest.mark.parametrize("case,planned", [
+    pytest.param(c, p, id=f"case{i}" + ("-plan" if p else ""))
+    for p in (False, True) for i, c in enumerate(IMAG_CASES)])
+def test_imag_ops_impls_vs_oracle(case, planned):
     K, B, obs, act, hid, phid, bb, mode = case
     members, norm, pol, s, eps = _imag_inputs(K, B, obs, act, hid, phid)
     if mode == "one":
         midx = jnp.full((B,), min(1, K - 1), jnp.int32)
     else:
         midx = jax.random.randint(jax.random.fold_in(KEY, 50), (B,), 0, K)
+    plan = imag_ops.sort_plan(midx, K) if planned else None
     exp = imag_ref.fused_step(members, norm, pol, s, eps, midx)
     got_flat = imag_ops.fused_step(members, norm, pol, s, eps, midx,
                                    impl="fused")
     got_pal = imag_ops.fused_step(members, norm, pol, s, eps, midx,
                                   impl="pallas", interpret=True,
-                                  block_b=bb)
+                                  block_b=bb, plan=plan)
     for got in (got_flat, got_pal):
         for e, g in zip(exp, got):
             np.testing.assert_allclose(np.asarray(g), np.asarray(e),
                                        atol=1e-4, rtol=1e-4)
 
 
+def test_imag_sort_plan_inverse():
+    midx = jax.random.randint(jax.random.fold_in(KEY, 51), (4, 37), 0, 5)
+    order, offsets, inv = imag_ops.sort_plan(midx, 5)
+    rows = np.broadcast_to(np.arange(37), (4, 37))
+    np.testing.assert_array_equal(
+        np.take_along_axis(np.asarray(inv), np.asarray(order), -1), rows)
+    np.testing.assert_array_equal(
+        np.take_along_axis(np.asarray(order), np.asarray(inv), -1), rows)
+    np.testing.assert_array_equal(np.asarray(offsets[:, -1]), 37)
+
+
+def test_imag_pallas_step_moves_rows_by_two_gathers():
+    """With the plan given, one step's rows go in by one gather and come
+    back by one gather; no scatter unsorts them (the kernel's own inner
+    jaxpr is not counted)."""
+    K, B, obs, act = 3, 24, 3, 1
+    members, norm, pol, s, eps = _imag_inputs(K, B, obs, act, 16, 8)
+    midx = jax.random.randint(jax.random.fold_in(KEY, 52), (B,), 0, K)
+    plan = imag_ops.sort_plan(midx, K)
+    jaxpr = jax.make_jaxpr(lambda *a: imag_ops.fused_step(
+        *a, impl="pallas", interpret=True, block_b=8, plan=plan))(
+        members, norm, pol, s, eps, midx)
+    prims = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    assert prims.count("gather") == 2, prims
+    assert not [p for p in prims if p.startswith("scatter")], prims
+
+
+def _scatter_unsort_step(members, norm, pol, s, eps, member_idx, *,
+                         plan, **_):
+    """The pallas step as spelled before the inverse permutation: two
+    row gathers in, one scatter per output back to input order."""
+    from repro.kernels.imag import pallas as imag_pallas
+    order, offsets, _ = plan
+    x = jnp.concatenate([s[order], eps[order]], axis=1)
+    out = imag_pallas.fused_step_sorted(members, norm, pol, x, offsets,
+                                        block_b=8, interpret=True)
+    unsort = lambda v: jnp.zeros_like(v).at[order].set(v)
+    o, a = s.shape[1], eps.shape[1]
+    return (unsort(out[:, :o]), unsort(out[:, o:o + a]),
+            unsort(out[:, o + a:]))
+
+
+def test_imag_pallas_rollout_matches_scatter_unsort_and_ref(monkeypatch):
+    """A whole horizon through ``_rollout_with_logp`` on the pallas path:
+    bitwise the rollout of the scatter spelling, and the oracle's to
+    float tolerance."""
+    from repro.mbrl import algos
+    K, B, obs, act, H = 3, 20, 5, 2, 6
+    members, norm, pol, _, _ = _imag_inputs(K, B, obs, act, 24, 12, i0=80)
+    params = {"members": members, "norm": norm}
+    s0 = rand((B, obs), 95)
+    # no sum over features: XLA may order a reduction differently around
+    # a gather than around a scatter, and the rows are what is compared
+    reward = lambda s, a, s2: s2[:, 0] * s[:, -1] - a[:, 0] ** 2
+
+    def rollout(impl, step):
+        monkeypatch.setattr(imag_ops, "default_impl", lambda: impl)
+        monkeypatch.setattr(imag_ops, "fused_step", step)
+        return algos._rollout_with_logp(params, pol, s0, jax.random.key(3),
+                                        H, reward)
+
+    fused_step = imag_ops.fused_step
+    new = rollout("pallas", lambda *a, interpret=False, **kw: fused_step(
+        *a, interpret=True, block_b=8, **kw))
+    old = rollout("pallas", _scatter_unsort_step)
+    ref = rollout("ref", fused_step)
+    for n, o, r in zip(new, old, ref):
+        np.testing.assert_array_equal(np.asarray(n), np.asarray(o))
+        np.testing.assert_allclose(np.asarray(n), np.asarray(r),
+                                   atol=1e-4, rtol=1e-4)
+
+
 def test_imag_pallas_grad_matches_ref():
     """MB-MPO differentiates THROUGH the fused step — the megakernel's
-    custom_vjp must agree with grads of the oracle."""
+    custom_vjp must agree with grads of the oracle, the step sorting its
+    own rows or given the plan."""
     K, B, obs, act, hid, phid = 3, 20, 3, 1, 16, 8
     members, norm, pol, s, eps = _imag_inputs(K, B, obs, act, hid, phid,
                                               i0=60)
     midx = jax.random.randint(jax.random.fold_in(KEY, 70), (B,), 0, K)
 
-    def loss(impl):
+    def loss(impl, plan=None):
         def f(mem, po, ss):
             s2, a, pre = imag_ops.fused_step(mem, norm, po, ss, eps, midx,
                                              impl=impl, interpret=True,
-                                             block_b=8)
+                                             block_b=8, plan=plan)
             return jnp.sum(s2 ** 2) + jnp.sum(a * pre)
         return jax.grad(f, argnums=(0, 1, 2))(members, pol, s)
 
     g_ref = loss("ref")
-    g_pal = loss("pallas")
-    for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_pal)):
-        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
-                                   atol=1e-4, rtol=1e-4)
+    for plan in (None, imag_ops.sort_plan(midx, K)):
+        g_pal = loss("pallas", plan)
+        for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_pal)):
+            np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                       atol=1e-4, rtol=1e-4)
