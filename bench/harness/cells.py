@@ -7,7 +7,10 @@ per-layer metric is a file of its own:
   and its traffic, and lists the metrics;
 * the configuration is the ``file`` its entry names (``bench/configs/``);
 * the traffic mix is ``bench/traffic/<traffic>.json``;
-* a per-layer metric is ``bench/metrics/<name>.py`` with ``read(ctx)``.
+* a per-layer metric is ``bench/metrics/<name>.py`` with ``read(ctx)``;
+* the configuration's ``algo`` is ``bench/algos/<algo>.py`` (``-`` as
+  ``_``): the reference's policy step, the check's first-step leaves and
+  the step's operation count.
 
 A later cell needs new files and entries only.
 """
@@ -16,10 +19,12 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+ALGOS = BENCH / "algos"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,3 +68,26 @@ def metric_reader(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def algorithm(name: str):
+    """The module ``bench/algos/<name>.py``, with ``-`` in ``name`` as
+    ``_``. It defines ``init(pol, c)``, the reference's policy-learner
+    state; ``step(state, model, key, c, mm, fault)`` -> ``(state,
+    imagined return)``, the reference's policy step; ``first_step(state0,
+    state1)``, the leaves the check compares for the first step, of the
+    program's state or the reference's; and ``step_ops(c)``, the
+    operations of one policy improvement. Loaded once a process, so its
+    jitted functions compile once."""
+    known = sorted(p.stem.replace("_", "-") for p in ALGOS.glob("*.py"))
+    if name not in known:
+        raise SystemExit(f"no algorithm {name!r} in {ALGOS}; known: {known}")
+    path = ALGOS / f"{name.replace('-', '_')}.py"
+    key = f"bench_algo_{path.stem}"
+    mod = sys.modules.get(key)
+    if mod is None or mod.__file__ != str(path):
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[key] = mod
+    return mod

@@ -16,9 +16,10 @@ The program's set-up steps (``runner.setup``) against the reference's
 * ``policy_return`` — policy learner: imagined return of each of its
   first three steps, largest gap over the largest magnitude of the
   three (a return near zero would blow a per-step relative gap up);
-* ``policy_step`` — the first step as the update takes it: the change of
-  the first TRPO step, or Adam's first moment after the first PPO step,
-  by the worst leaf;
+* ``policy_step`` — the first step as the update takes it, as the
+  algorithm's file says (``bench/algos/<algo>.py::first_step``: the
+  change of the first TRPO step, Adam's first moment after the first PPO
+  step), by the worst leaf;
 * ``policy_change`` — the policy's change over three steps, by the worst
   leaf;
 * ``window_ring`` — ring ingest in the window: the ring as the window
@@ -46,21 +47,22 @@ import math
 import jax
 import numpy as np
 
+from . import cells
 from .reference import ring_layout
 
 LEAF_FLOOR = 1e-3       # gradient share under which a leaf does not move
 
 
-def _leaves(tree):
+def leaves(tree):
     return [np.asarray(x, np.float64) for x in jax.tree.leaves(tree)]
 
 
 def _norms(tree):
-    return np.array([np.linalg.norm(x) for x in _leaves(tree)])
+    return np.array([np.linalg.norm(x) for x in leaves(tree)])
 
 
-def _delta(a, b):
-    return [x - y for x, y in zip(_leaves(a), _leaves(b))]
+def delta(a, b):
+    return [x - y for x, y in zip(leaves(a), leaves(b))]
 
 
 def worst_leaf(got, ref, keep=None) -> float:
@@ -116,11 +118,6 @@ def _policy(state):
     return state["policy"]
 
 
-def _adam_m(state):
-    opt = state["opt"]
-    return opt["m"] if isinstance(opt, dict) else opt.mu
-
-
 def numbers(prog: dict, ref: dict, config: dict) -> dict:
     """Each compared number, from the two sides' set-up records."""
     out = {}
@@ -130,25 +127,21 @@ def numbers(prog: dict, ref: dict, config: dict) -> dict:
                            prog.get("ring_full", True))
     n = 3
     out["model_loss"] = rel_gap(prog["val_loss"][:n], ref["val_loss"][:n])
-    out["model_grad"] = worst_leaf(_leaves(prog["model_opt1"]),
-                                   _leaves(ref["model_opt1"]))
+    out["model_grad"] = worst_leaf(leaves(prog["model_opt1"]),
+                                   leaves(ref["model_opt1"]))
     keep = moving(ref["model_opt1"])
     out["model_change"] = worst_leaf(
-        _delta(prog["model3"], prog["model0"]),
-        _delta(ref["model3"], ref["model0"]), keep)
+        delta(prog["model3"], prog["model0"]),
+        delta(ref["model3"], ref["model0"]), keep)
     out["policy_return"] = field_gap(
         {"return": prog["imagined_return"][:n]},
         {"return": ref["imagined_return"][:n]})
-    if config["algo"] == "me-ppo":
-        out["policy_step"] = worst_leaf(_leaves(_adam_m(prog["policy1"])),
-                                        _leaves(_adam_m(ref["policy1"])))
-    else:
-        out["policy_step"] = worst_leaf(
-            _delta(_policy(prog["policy1"]), _policy(prog["policy0"])),
-            _delta(_policy(ref["policy1"]), _policy(ref["policy0"])))
+    first = cells.algorithm(config["algo"]).first_step
+    out["policy_step"] = worst_leaf(first(prog["policy0"], prog["policy1"]),
+                                    first(ref["policy0"], ref["policy1"]))
     out["policy_change"] = worst_leaf(
-        _delta(_policy(prog["policy3"]), _policy(prog["policy0"])),
-        _delta(_policy(ref["policy3"]), _policy(ref["policy0"])))
+        delta(_policy(prog["policy3"]), _policy(prog["policy0"])),
+        delta(_policy(ref["policy3"]), _policy(ref["policy0"])))
     if "window" in prog:
         w = prog["window"]
         out["window_ring"] = (

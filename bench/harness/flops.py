@@ -1,10 +1,15 @@
 """Operations and bytes the algorithms need, computed from shapes.
 
+The parts every algorithm shares are here; what one policy improvement
+of an algorithm adds is its file's ``step_ops`` (``bench/algos/``).
+
 Counts are of matrix products (2 operations a multiply-add), in float32
 (4 bytes). Nothing recomputed is counted, and an ensemble imagination
 row costs one member, as the algorithm samples one.
 """
 from __future__ import annotations
+
+from . import cells
 
 F32 = 4
 
@@ -44,22 +49,20 @@ def imag_call(c) -> tuple:
     return ops, byts
 
 
+def imagination(c) -> int:
+    """Operations of one imagined rollout over the batch."""
+    return c["imagine_horizon"] * imag_call(c)[0]
+
+
+def policy_pass(c) -> int:
+    """Operations of one pass of one row through the policy."""
+    return 2 * macs(_dims(c, True))
+
+
 def policy_step(c) -> int:
-    """One policy improvement: imagination, then the update. TRPO: the
-    surrogate's gradient (3 policy passes), the old policy's statistics
-    (1), eleven Fisher-vector products on every fourth row (a jvp and a
-    vjp, 4 passes each) and ten line-search candidates (1 each). PPO:
-    the old log-probabilities and one gradient (4 passes)."""
-    rows = imagine_rows(c)
-    ops = c["imagine_horizon"] * imag_call(c)[0]
-    pm = macs(_dims(c, True))
-    if c["algo"] == "me-trpo":
-        stride = max(1, min(4, rows // 256))
-        passes = 3 + 1 + 10
-        ops += 2 * pm * (passes * rows + 11 * 4 * (rows // stride))
-    else:
-        ops += 2 * pm * 4 * rows
-    return ops
+    """One policy improvement, as the configuration's algorithm counts it
+    (``bench/algos/<algo>.py::step_ops``)."""
+    return cells.algorithm(c["algo"]).step_ops(c)
 
 
 def model_batches(c) -> int:

@@ -4,9 +4,12 @@ Straightforward ``jax.numpy`` in float32, written from the algorithms'
 descriptions and sharing no code with the program: the arm's dynamics
 and reward, the tanh-Gaussian policy, the farm's rollouts, the FIFO ring
 with its held-out trajectories, the dynamics ensemble (every member on
-every row) with its normaliser and Adam, imagination from the ensemble,
-and the TRPO and PPO updates. (The set-up's model steps each follow a
-landing, which resets the program's early stop, so none can stop.) It builds its own
+every row) with its normaliser and Adam, imagination from the ensemble
+and the batch of advantages a policy step learns from. (The set-up's
+model steps each follow a landing, which resets the program's early
+stop, so none can stop.) The policy learner's state and step are the
+algorithm's own file, ``bench/algos/<algo>.py``, found by the
+configuration's ``algo`` (``cells.algorithm``). It builds its own
 weights and data from the seed, drawing them as the configuration's
 random streams prescribe, and never sees what the program made.
 
@@ -29,6 +32,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from . import cells
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -347,100 +352,18 @@ def advantages(rew, gamma):
     return (adv - adv.mean()) / (adv.std() + 1e-8)
 
 
-def tdot(a, b):
-    return sum(jnp.sum(x * y) for x, y in
-               zip(jax.tree.leaves(a), jax.tree.leaves(b)))
-
-
-def trpo(pol, obs, pre, adv, weights, c, mm):
-    """Natural-gradient step: ten conjugate-gradient iterations on the
-    Gauss-Newton Fisher of every fourth row (damping 1e-2), scaled to the
-    KL radius, then the first of ten backtracking fractions 0.8**i whose
-    KL stays within 1.5x the radius and whose surrogate is positive."""
-    cands, kls, surrs = _trpo_candidates(pol, obs, pre, adv, weights,
-                                         c["max_kl"], mm)
-    for i in range(len(kls)):
-        if kls[i] <= c["max_kl"] * 1.5 and surrs[i] > 0:
-            return jax.tree.map(lambda x: x[i], cands)
-    return pol
-
-
-@functools.partial(jax.jit, static_argnums=(5, 6))
-def _trpo_candidates(pol, obs, pre, adv, weights, max_kl, mm):
-    mean = lambda p, o: policy_mean(p, o, mm)
-    mu0, ls0 = mean(pol, obs), pol["log_std"]
-    v0 = jnp.exp(2 * ls0)
-    lp0 = log_prob(pol, obs, pre, mm)
-    wsum = jnp.maximum(weights.sum(), 1.0)
-
-    def surr(p):
-        return jnp.sum(jnp.exp(log_prob(p, obs, pre, mm) - lp0) * adv
-                       * weights) / wsum
-
-    def kl(p):
-        mu1, ls1 = mean(p, obs), p["log_std"]
-        v1 = jnp.exp(2 * ls1)
-        per = (ls1 - ls0 + (v0 + (mu0 - mu1) ** 2) / (2 * v1) - 0.5).sum(-1)
-        return jnp.sum(per * weights) / wsum
-
-    g = jax.grad(surr)(pol)
-    stride = max(1, min(4, obs.shape[0] // 256))
-    ofv = obs[::stride]
-    nf = ofv.shape[0]
-    _, vjp = jax.vjp(lambda p: mean(p, ofv), pol)
-
-    def fvp(v):
-        jv = jax.jvp(lambda p: mean(p, ofv), (pol,), (v,))[1]
-        out = vjp(jv / v0 / nf)[0]
-        return {**out, "log_std": out["log_std"] + 2.0 * v["log_std"]}
-
-    add = lambda a, b, s=1.0: jax.tree.map(lambda x, y: x + s * y, a, b)
-    x = jax.tree.map(jnp.zeros_like, g)
-    r = p = g
-    rs = tdot(r, r)
-    for _ in range(10):
-        hp = add(fvp(p), p, 1e-2)
-        alpha = rs / (tdot(p, hp) + 1e-10)
-        x = add(x, p, alpha)
-        r = add(r, hp, -alpha)
-        rs_new = tdot(r, r)
-        p = add(r, p, rs_new / (rs + 1e-10))
-        rs = rs_new
-    lm = jnp.sqrt(jnp.maximum(tdot(x, fvp(x)), 1e-10) / (2 * max_kl))
-    full = jax.tree.map(lambda v: v / jnp.maximum(lm, 1e-10), x)
-    fracs = 0.8 ** jnp.arange(10, dtype=jnp.float32)
-    cands = jax.vmap(lambda f: add(pol, full, f))(fracs)
-    kls, surrs = jax.vmap(lambda cand: (kl(cand), surr(cand)))(cands)
-    return cands, kls, surrs
-
-
-def ppo(pol, opt, obs, pre, adv, weights, c, mm):
-    """One clipped-surrogate (0.2) Adam step from the pre-step policy."""
-    lp0 = log_prob(pol, obs, pre, mm)
-
-    def loss(p):
-        ratio = jnp.exp(log_prob(p, obs, pre, mm) - lp0)
-        per = jnp.minimum(ratio * adv, jnp.clip(ratio, 0.8, 1.2) * adv)
-        return -jnp.sum(per * weights) / jnp.maximum(weights.sum(), 1.0)
-
-    g = jax.grad(loss)(pol)
-    return adam_update(pol, g, opt, c["ppo_lr"])
-
-
-def policy_step(state, model, key, c, mm, fault):
+def policy_batch(state, model, key, c, mm, fault):
+    """What a policy step of the ME algorithms learns from: imagination
+    from the state's policy, flattened to rows of observations, pre-tanh
+    actions and standardised advantages, the rows' weights (half of them
+    under the half-batch fault), and the imagined return."""
     obs, pre, rew = _imagine(model, state["policy"], key, _frozen(c), mm)
     adv = advantages(rew, c["gamma"]).reshape(-1)
     obs = obs.reshape(-1, obs.shape[-1])
     pre = pre.reshape(-1, pre.shape[-1])
     ones = jnp.ones(obs.shape[0])
     weights = half(ones) if fault == "half_batch" else ones
-    ret = float(rew.sum(0).mean())
-    if c["algo"] == "me-trpo":
-        new = trpo(state["policy"], obs, pre, adv, weights, c, mm)
-        return {**state, "policy": new}, ret
-    new, opt = ppo(state["policy"], state["opt"], obs, pre, adv, weights,
-                   c, mm)
-    return {**state, "policy": new, "opt": opt}, ret
+    return obs, pre, adv, weights, float(rew.sum(0).mean())
 
 
 class _frozen(dict):
@@ -465,9 +388,8 @@ def run_setup(c, traffic, seed, rounds, steps=3, mm=Matmul(), fault=None):
     k_pol, k_pinit = jax.random.split(k_pol)
     model = ensemble_init(k_init, c)
     pol = policy_init(k_pinit, c)
-    state = {"policy": pol}
-    if c["algo"] == "me-ppo":
-        state["opt"] = adam_init(pol)
+    algo = cells.algorithm(c["algo"])
+    state = algo.init(pol, c)
     n = traffic["robots_per_collector"]
     every = max(int(round(1 / c["holdout_frac"])), 2)
     rows = c["ring_trajs"] * c["horizon"]
@@ -500,7 +422,7 @@ def run_setup(c, traffic, seed, rounds, steps=3, mm=Matmul(), fault=None):
     out["ring"], out["ring_val"] = ring.train, ring.val
     for s in range(steps):
         k_pol, k = jax.random.split(k_pol)
-        state, ret = policy_step(state, model, k, c, mm, fault)
+        state, ret = algo.step(state, model, k, c, mm, fault)
         out["imagined_return"].append(ret)
         if s == 0:
             out["policy1"] = state

@@ -21,6 +21,7 @@ and the engine joins its threads.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import math
 import threading
 import time
@@ -50,13 +51,22 @@ def fill_rounds(config: dict, robots: int) -> int:
     return max(t // robots, CHECKED_STEPS)
 
 
+def algo_config(config: dict):
+    """The program's ``AlgoConfig`` from every field of it that the
+    configuration names (``algo`` is required; the rest keep the
+    program's defaults)."""
+    from repro.mbrl import AlgoConfig
+    names = {f.name for f in dataclasses.fields(AlgoConfig)}
+    named = {k: v for k, v in config.items() if k in names}
+    return AlgoConfig(**{**named, "algo": config["algo"]})
+
+
 def build(config: dict, traffic: dict, seed: int):
     """The trainer ``launch/train.py --task mbrl`` builds, at the
     configuration's sizes."""
     from repro.core import AsyncTrainer, RunConfig
     from repro.envs import make_env
-    from repro.mbrl import (AlgoConfig, EnsembleConfig, PolicyConfig,
-                            make_algo)
+    from repro.mbrl import EnsembleConfig, PolicyConfig, make_algo
     env = make_env(config["env"])
     dims = (env.obs_dim, env.act_dim, env.horizon, round(1 / env.dt))
     want = (config["obs_dim"], config["act_dim"], config["horizon"],
@@ -74,11 +84,7 @@ def build(config: dict, traffic: dict, seed: int):
                        hidden=config["policy_hidden"],
                        depth=config["policy_depth"],
                        init_log_std=config["policy_init_log_std"])
-    acfg = AlgoConfig(algo=config["algo"],
-                      imagine_batch=config["imagine_batch"],
-                      imagine_horizon=config["imagine_horizon"],
-                      gamma=config["gamma"], max_kl=config["max_kl"],
-                      ppo_lr=config["ppo_lr"], n_models=config["n_models"])
+    acfg = algo_config(config)
     algo = make_algo(acfg, pol, jax.vmap(env.reward), env.reset_batch)
     rc = RunConfig(total_trajs=OUT_OF_REACH, seed=seed,
                    n_collectors=traffic["collectors"],

@@ -16,9 +16,10 @@ Runs one cell of ``BENCHMARK.json`` on the chip this process holds:
 3. window — the engine runs ``--seconds`` under the cell's pacing; with
    ``--trace 1`` the profiler records it;
 4. correctness — the set-up steps against the plain reference
-   (``harness/reference.py``, ``harness/check.py``), and the ring the
-   window's drains left against the reference's layout of what they
-   moved, after the window, with the program's state freed.
+   (``harness/reference.py``, with the policy step of the configuration's
+   algorithm from ``algos/<algo>.py``; ``harness/check.py``), and the
+   ring the window's drains left against the reference's layout of what
+   they moved, after the window, with the program's state freed.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``
 and ``failed`` (numbers compared, and over their limits), ``metrics``
@@ -93,6 +94,7 @@ def main(argv=None) -> int:
             sys.path.insert(0, p)
     from harness import cells
     cell = cells.load(args.workload)
+    cells.algorithm(cell.config["algo"])    # an unknown one stops the run
     configure_jax(cell.config)
     check_device(cell.chips)
 
