@@ -12,9 +12,10 @@ dispatchable unit. ``impl``:
   slab each way: ``[s | eps]`` goes in by one gather (``order``) and
   the kernel's ``[s2 | a | pre]`` comes back by one gather (``inv``);
   a row gather costs half a row scatter on the chip, and each costs by
-  the row, not the byte. Differentiable: a ``custom_vjp`` backs the
-  kernel with the jnp reference's VJP, so MB-MPO's gradients THROUGH
-  the rollout keep working (a gather differentiates natively).
+  the row, not the byte. Differentiable like the other impls: a
+  ``custom_vjp`` backs the kernel with the jnp reference's VJP (a gather
+  differentiates natively). No trainer path differentiates a rollout:
+  MB-MPO samples from parameters held fixed.
 * ``fused`` — the XLA-fused flat spelling: the policy head feeds
   straight into one flattened ``(B, din) @ (din, K*dout)`` matmul per
   dynamics layer with a per-layer member gather. K*B FLOPs, but tiny
@@ -93,9 +94,12 @@ def _fused_flat(members, norm, pol, s, eps, member_idx):
 
 
 # ---------------------------------------------------------------- pallas
-# The kernel has no autodiff rule; MB-MPO differentiates THROUGH the
-# rollout, so the pallas impl carries a custom_vjp whose backward pass is
-# the VJP of the jnp reference on the same sorted rows: ``x`` is the
+# The kernel has no autodiff rule, so the pallas impl carries a
+# custom_vjp, and ``jax.grad`` through a step works on every impl alike
+# (a caller that differentiates imagination need not pick one). The
+# backward pass is the VJP of the jnp reference on the same sorted rows;
+# differentiating it again under a vmap over member slices does not
+# trace (JAX 0.9.0), and nothing in the trainer does. ``x`` is the
 # (B, obs+act) slab ``[s | eps]`` in member order, the output the
 # (B, obs+2*act) slab ``[s2 | a | pre]`` in the same order.
 

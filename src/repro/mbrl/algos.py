@@ -11,8 +11,8 @@ the current dynamics model and take ONE policy-gradient (TRPO/PPO) step.
 
 * ME-TRPO  [10]: imagined rollouts from the ensemble -> TRPO step.
 * ME-PPO   [paper §5.1]: same, PPO clipped step.
-* MB-MPO   [4]: per-model inner VPG adaptation, outer PPO step on the
-  post-adaptation surrogate (meta-policy optimization).
+* MB-MPO   [4]: per-model inner VPG adaptation, one outer Adam step on
+  the post-adaptation surrogate (meta-policy optimization).
 """
 from __future__ import annotations
 
@@ -194,9 +194,19 @@ class MEAlgo(_MeshMixin):
 class MBMPO(_MeshMixin):
     """MB-MPO [4]: meta-policy optimization over the model ensemble.
 
-    Inner loop: for each ensemble member m, adapt theta with one VPG step
-    on imagined data from member m. Outer loop: PPO step on the
-    post-adaptation surrogate averaged over members.
+    For each ensemble member m: adapt theta by one VPG step of
+    ``inner_lr`` on a rollout imagined from member m alone, giving
+    theta'_m, then imagine a new rollout from member m with theta'_m.
+    The outer step is one Adam step of ``ppo_lr`` on the members' mean
+    outer surrogate, differentiated through the inner step.
+
+    The estimator is MAML's, as MB-MPO uses it: every rollout samples
+    from parameters held fixed (``stop_gradient``), each loss is the
+    likelihood-ratio surrogate on its own samples, and the meta-gradient
+    flows only through theta'_m(theta) = theta - inner_lr * grad
+    L_in(theta): the dependence of the sampling distribution on theta is
+    not differentiated. No rollout is differentiated, so the second
+    order touches the policy MLP alone.
 
     On a role mesh the whole meta-step runs replicated over the policy
     sub-mesh (params placement, core/workers.py); the per-member vmap
@@ -232,46 +242,74 @@ class MBMPO(_MeshMixin):
             model_params["members"])
         return {"members": members, "norm": model_params["norm"]}
 
-    def _vpg_loss(self, pol, member, s0, key):
-        obs, pre, rew = _rollout_with_logp(member, pol, s0, key,
-                                           self.cfg.imagine_horizon,
-                                           self.reward_fn, self.predict_fn)
-        batch = _flat_batch(obs, pre, rew, self.cfg.gamma)
+    def _imagine(self, pol, member, key):
+        """One rollout from ``member`` alone, sampled from ``pol`` held
+        fixed: the flat batch a surrogate reads, and the imagined
+        return."""
+        cfg = self.cfg
+        # independent keys for start-state draws and rollout sampling
+        # (reusing one for both correlates the action noise with the
+        # start states)
+        k_s0, k_roll = jax.random.split(key)
+        s0 = self.init_state_fn(k_s0, cfg.imagine_batch)
+        obs, pre, rew = _rollout_with_logp(
+            member, jax.lax.stop_gradient(pol), s0, k_roll,
+            cfg.imagine_horizon, self.reward_fn, self.predict_fn)
+        return _flat_batch(obs, pre, rew, cfg.gamma), rew.sum(0).mean()
+
+    @staticmethod
+    def _surrogate(pol, batch):
+        """VPG's likelihood-ratio surrogate on samples held fixed."""
         lp = PI.log_prob(pol, batch["obs"], batch["act_pre"])
-        return -(lp * batch["adv"]).mean(), rew.sum(0).mean()
+        return -(lp * batch["adv"]).mean()
+
+    def _adapt(self, theta, batch):
+        """theta'_m: one VPG step of ``inner_lr`` on the inner batch."""
+        g = jax.grad(self._surrogate)(theta, batch)
+        return jax.tree.map(lambda p, gg: p - self.cfg.inner_lr * gg,
+                            theta, g)
+
+    def meta_gradient(self, theta, model_params, key):
+        """The gradient of the members' mean outer surrogate through the
+        inner step, the K adapted policies (stacked on a leading member
+        axis) and ``info``. Member m draws its inner and outer rollouts
+        from the two halves of the m-th of K keys split from ``key``."""
+        K = self.cfg.n_models
+        ms = jnp.arange(K)
+        keys = jax.vmap(jax.random.split)(jax.random.split(key, K))
+        k_in, k_out = keys[:, 0], keys[:, 1]
+
+        def rollout(args):
+            pol, m, k = args
+            return self._imagine(pol, self._member_params(model_params, m),
+                                 k)
+
+        # the members' rollouts run one after another (lax.map), not under
+        # vmap: batching the kernel's offsets loops its calls, and XLA
+        # fuses each call with the loop's write of its output, so the
+        # kernel no longer shows as one operation in a device trace
+        with jax.named_scope("mbmpo.inner"):
+            batch_in, _ = jax.lax.map(
+                lambda mk: rollout((theta,) + mk), (ms, k_in))
+            adapted, through_inner = jax.vjp(
+                lambda th: jax.vmap(self._adapt, (None, 0))(th, batch_in),
+                theta)
+        with jax.named_scope("mbmpo.outer"):
+            batch_out, rets = jax.lax.map(rollout, (adapted, ms, k_out))
+            loss, g_out = jax.value_and_grad(
+                lambda a: jax.vmap(self._surrogate)(a, batch_out).mean())(
+                adapted)
+        with jax.named_scope("mbmpo.meta_grad"):
+            g, = through_inner(g_out)
+        return g, adapted, {"meta_loss": loss,
+                            "imagined_return": rets.mean()}
 
     def _improve_impl(self, state, model_params, key):
-        cfg = self.cfg
         pol = state["policy"]
-        K = cfg.n_models
-
-        def meta_loss(theta, key):
-            def per_member(m, k):
-                member = self._member_params(model_params, m)
-                k_in, k_out = jax.random.split(k)
-                # independent keys for start-state draws and rollout
-                # sampling (reusing k_in for both correlates the inner
-                # rollout's action noise with the start states)
-                k_s0_in, k_roll_in = jax.random.split(k_in)
-                s0 = self.init_state_fn(k_s0_in, cfg.imagine_batch)
-                (l_in, _), g = jax.value_and_grad(
-                    self._vpg_loss, has_aux=True)(theta, member, s0,
-                                                  k_roll_in)
-                adapted = jax.tree.map(lambda p, gg: p - cfg.inner_lr * gg,
-                                       theta, g)
-                k_s0_out, k_roll_out = jax.random.split(k_out)
-                s1 = self.init_state_fn(k_s0_out, cfg.imagine_batch)
-                l_out, ret = self._vpg_loss(adapted, member, s1, k_roll_out)
-                return l_out, ret
-
-            keys = jax.random.split(key, K)
-            losses, rets = jax.vmap(per_member)(jnp.arange(K), keys)
-            return losses.mean(), rets.mean()
-
-        (loss, ret), g = jax.value_and_grad(meta_loss, has_aux=True)(pol, key)
-        upd, opt = self._outer_opt.update(g, state["opt"], pol)
-        new_pol = apply_updates(pol, upd)
-        info = {"meta_loss": loss, "imagined_return": ret}
+        g, _, info = self.meta_gradient(pol, model_params, key)
+        with jax.named_scope("mbmpo.meta_grad"):
+            upd, opt = self._outer_opt.update(g, state["opt"], pol)
+            new_pol = apply_updates(pol, upd)
         return ({"policy": new_pol, "opt": opt,
                  "steps": state["steps"] + 1}, info)
 

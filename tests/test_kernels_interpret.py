@@ -293,9 +293,9 @@ def test_imag_pallas_rollout_matches_scatter_unsort_and_ref(monkeypatch):
 
 
 def test_imag_pallas_grad_matches_ref():
-    """MB-MPO differentiates THROUGH the fused step — the megakernel's
-    custom_vjp must agree with grads of the oracle, the step sorting its
-    own rows or given the plan."""
+    """The fused step stays differentiable on every impl — the
+    megakernel's custom_vjp must agree with grads of the oracle, the step
+    sorting its own rows or given the plan."""
     K, B, obs, act, hid, phid = 3, 20, 3, 1, 16, 8
     members, norm, pol, s, eps = _imag_inputs(K, B, obs, act, hid, phid,
                                               i0=60)
@@ -315,3 +315,72 @@ def test_imag_pallas_grad_matches_ref():
         for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_pal)):
             np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                        atol=1e-4, rtol=1e-4)
+
+
+def test_imag_pallas_vmapped_over_member_slices():
+    """The pallas step vmapped over K one-member slices of the ensemble,
+    each with its own rows, noise and plan, so the scalar-prefetch
+    offsets are batched too. JAX lowers that to one kernel call per
+    slice; each must be the unbatched call's, and the oracle's to float
+    tolerance. (MB-MPO maps its members' rollouts with ``lax.map``
+    instead, which keeps each call a lone operation in a device trace.)"""
+    K, B, obs, act = 3, 20, 5, 2
+    members, norm, pol, _, _ = _imag_inputs(K, B, obs, act, 24, 12, i0=100)
+    sliced = jax.tree.map(lambda x: x[:, None], members)    # (K, 1, ...)
+    s = rand((K, B, obs), 120)
+    eps = rand((K, B, act), 121)
+    midx = jnp.zeros((K, B), jnp.int32)
+
+    def one(m, s, eps, idx):
+        return imag_ops.fused_step(m, norm, pol, s, eps, idx, impl="pallas",
+                                   interpret=True, block_b=8,
+                                   plan=imag_ops.sort_plan(idx, 1))
+
+    got = jax.vmap(one)(sliced, s, eps, midx)
+    for k in range(K):
+        m = jax.tree.map(lambda x: x[k], sliced)
+        alone = one(m, s[k], eps[k], midx[k])
+        exp = imag_ref.fused_step(m, norm, pol, s[k], eps[k], midx[k])
+        for g, a, e in zip(got, alone, exp):
+            np.testing.assert_array_equal(np.asarray(g[k]), np.asarray(a))
+            np.testing.assert_allclose(np.asarray(g[k]), np.asarray(e),
+                                       atol=1e-4, rtol=1e-4)
+
+
+def test_mbmpo_improve_pallas_matches_fused(monkeypatch):
+    """One MB-MPO step with imagination on the interpreted pallas path
+    (one one-member call per member and horizon step) against the
+    XLA-fused path: the same rollouts to float tolerance, so the same
+    meta-gradient."""
+    from repro.envs import make_env
+    from repro.mbrl import dynamics as DYN
+    from repro.mbrl.algos import AlgoConfig, make_algo
+    from repro.mbrl.policy import PolicyConfig
+    env = make_env("pendulum")
+    model = DYN.init_ensemble(DYN.EnsembleConfig(
+        env.obs_dim, env.act_dim, hidden=24, n_models=3), jax.random.key(8))
+    acfg = AlgoConfig(algo="mb-mpo", imagine_batch=20, imagine_horizon=4,
+                      n_models=3)
+    fused_step = imag_ops.fused_step
+
+    def improve(impl, step):
+        monkeypatch.setattr(imag_ops, "default_impl", lambda: impl)
+        monkeypatch.setattr(imag_ops, "fused_step", step)
+        algo = make_algo(acfg, PolicyConfig(env.obs_dim, env.act_dim,
+                                            hidden=16),
+                         jax.vmap(env.reward), env.reset_batch)
+        state = algo.init(jax.random.key(9))
+        return algo.improve(state, model, jax.random.key(10))
+
+    pal, pal_info = improve("pallas", lambda *a, interpret=False, **kw:
+                            fused_step(*a, interpret=True, block_b=8, **kw))
+    ref, ref_info = improve("fused", fused_step)
+    np.testing.assert_allclose(float(pal_info["imagined_return"]),
+                               float(ref_info["imagined_return"]),
+                               rtol=1e-5)
+    # Adam's first moment is the meta-gradient; the parameters took
+    # Adam's normalised step, which rounding near zero can flip
+    for a, b in zip(jax.tree.leaves(pal["opt"].mu),
+                    jax.tree.leaves(ref["opt"].mu)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-4)

@@ -182,3 +182,111 @@ def test_advantage_computation():
     np.testing.assert_allclose(np.asarray(rtg[:, 0]),
                                [1.9375, 1.875, 1.75, 1.5, 1.0], rtol=1e-5)
     assert abs(float(adv.mean())) < 1e-5
+
+
+# ------------------------------------------------------------------ MB-MPO
+# MB-MPO's estimator is MAML's: every rollout samples from parameters held
+# fixed, each loss is VPG's likelihood-ratio surrogate on its own samples,
+# and the meta-gradient flows only through theta'_m = theta - a * grad L_in.
+# The tests below rebuild each member's rollouts as the program draws them
+# and do the calculus in plain jnp.
+
+def _mbmpo(inner_lr):
+    env = make_env("pendulum")
+    ens_cfg = DYN.EnsembleConfig(env.obs_dim, env.act_dim, hidden=32,
+                                 n_models=2)
+    key = jax.random.key(5)
+    model = DYN.init_ensemble(ens_cfg, key)
+    acfg = AlgoConfig(algo="mb-mpo", imagine_batch=32, imagine_horizon=6,
+                      n_models=2, inner_lr=inner_lr)
+    algo = make_algo(acfg, PolicyConfig(env.obs_dim, env.act_dim, hidden=16),
+                     jax.vmap(env.reward), env.reset_batch)
+    return env, algo, model, algo.init(jax.random.fold_in(key, 1))
+
+
+def _member_batch(env, algo, model, pol, m, key):
+    """Member m's rollout from ``pol``, drawn as the program draws it
+    (starts from the first half of ``key``, sampling from the second),
+    flattened to observations, pre-tanh actions and advantages."""
+    from repro.mbrl import algos
+    cfg = algo.cfg
+    member = {"members": jax.tree.map(lambda x: x[m:m + 1],
+                                      model["members"]),
+              "norm": model["norm"]}
+    k_s0, k_roll = jax.random.split(key)
+    obs, pre, rew = algos._rollout_with_logp(
+        member, pol, env.reset_batch(k_s0, cfg.imagine_batch), k_roll,
+        cfg.imagine_horizon, jax.vmap(env.reward))
+    _, adv = TRPO.compute_advantages(rew, gamma=cfg.gamma)
+    return (obs.reshape(-1, obs.shape[-1]), pre.reshape(-1, pre.shape[-1]),
+            adv.reshape(-1))
+
+
+def _vpg(pol, obs, pre, adv):
+    """VPG's surrogate, written out: minus the mean of the Gaussian
+    log-density of each pre-tanh action under the tanh-MLP mean, times
+    its advantage."""
+    h = obs
+    for i, (w, b) in enumerate(zip(pol["w"], pol["b"])):
+        h = h @ w + b
+        if i < len(pol["w"]) - 1:
+            h = jnp.tanh(h)
+    z = (pre - h) / jnp.exp(pol["log_std"])
+    lp = (-0.5 * z ** 2 - pol["log_std"] - 0.5 * jnp.log(2 * jnp.pi)).sum(-1)
+    return -(lp * adv).mean()
+
+
+def _member_keys(key, n_models):
+    return [jax.random.split(k) for k in jax.random.split(key, n_models)]
+
+
+def test_mbmpo_inner_step_is_vpg_on_its_own_rollout():
+    lr = 0.05
+    env, algo, model, state = _mbmpo(lr)
+    theta = state["policy"]
+    key = jax.random.key(21)
+    _, adapted, _ = algo.meta_gradient(theta, model, key)
+    for m, (k_in, _) in enumerate(_member_keys(key, 2)):
+        g = jax.grad(_vpg)(theta, *_member_batch(env, algo, model, theta, m,
+                                                 k_in))
+        for t, a, gg in zip(jax.tree.leaves(theta), jax.tree.leaves(adapted),
+                            jax.tree.leaves(g)):
+            # theta - theta'_m carries theta's own rounding (|theta| <= 2,
+            # an ulp 2.4e-7); the two gradients are the same float32 sums
+            # in another order, a few ulps of the gradient apart
+            np.testing.assert_allclose(np.asarray(t - a[m]),
+                                       np.asarray(lr * gg),
+                                       rtol=1e-4, atol=1e-6)
+
+
+def test_mbmpo_meta_gradient_is_through_the_inner_step():
+    lr = 0.5        # large enough that the second-order term shows
+    env, algo, model, state = _mbmpo(lr)
+    theta = state["policy"]
+    key = jax.random.key(22)
+    new, _ = algo.improve(state, model, key)
+    # Adam's first moment after one step from zero is (1 - b1) * g
+    got = jax.tree.map(lambda mu: mu / 0.1, new["opt"].mu)
+    want = jax.tree.map(jnp.zeros_like, theta)
+    first = jax.tree.map(jnp.zeros_like, theta)
+    for m, (k_in, k_out) in enumerate(_member_keys(key, 2)):
+        inner = _member_batch(env, algo, model, theta, m, k_in)
+        grad_in = lambda th: jax.grad(_vpg)(th, *inner)
+        adapted = jax.tree.map(lambda t, g: t - lr * g, theta, grad_in(theta))
+        v = jax.grad(_vpg)(adapted, *_member_batch(env, algo, model, adapted,
+                                                   m, k_out))
+        hv = jax.jvp(grad_in, (theta,), (v,))[1]
+        # (I - lr * H_in) grad L_out(theta'_m), averaged over members
+        want = jax.tree.map(lambda w, a, b: w + (a - lr * b) / 2, want, v, hv)
+        first = jax.tree.map(lambda w, a: w + a / 2, first, v)
+    scale = max(float(jnp.abs(x).max()) for x in jax.tree.leaves(want))
+    second = max(float(jnp.abs(w - f).max()) for w, f in
+                 zip(jax.tree.leaves(want), jax.tree.leaves(first)))
+    assert second > 0.05 * scale        # the test tells MAML from FO-MAML
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        # float32 sums in another order, and the test's theta'_m a few
+        # ulps off the program's, so its outer rollout too (they agree to
+        # about 1e-7 of the largest element): 1e-5 of it, far under the
+        # second-order term's share
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5 * scale)
